@@ -16,7 +16,8 @@ from repro.core.grid_client import GridSignalClient
 from repro.core.controller import SproutSimulation, SchemeStats, summarize
 from repro.core.directives import DEFAULT_DIRECTIVES, Directive, DirectiveSet
 from repro.core.energy import (A100_40GB, TPU_V5E, LLAMA2_7B, LLAMA2_13B,
-                               EnergyModel, HardwareSpec, ModelProfile)
+                               EnergyModel, HardwareSpec, ModelProfile,
+                               hardware_for)
 from repro.core.invoker import EvaluationInvoker
 from repro.core.lp import (BATCH, DEFAULT_TENANTS, PREMIUM, STANDARD,
                            DirectiveSolution, TenantSpec,
@@ -31,6 +32,7 @@ __all__ = [
     "request_carbon", "PUE", "SproutSimulation", "SchemeStats", "summarize",
     "DEFAULT_DIRECTIVES", "Directive", "DirectiveSet", "A100_40GB", "TPU_V5E",
     "LLAMA2_7B", "LLAMA2_13B", "EnergyModel", "HardwareSpec", "ModelProfile",
+    "hardware_for",
     "EvaluationInvoker", "DirectiveSolution", "quality_lower_bound",
     "solve_directive_lp", "solve_tenant_lps", "TenantSpec", "PREMIUM",
     "STANDARD", "BATCH", "DEFAULT_TENANTS", "EvaluationReport",

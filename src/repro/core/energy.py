@@ -41,6 +41,25 @@ TPU_V5E = HardwareSpec(
     power_peak=220.0, power_idle=60.0, embodied_gco2=120_000.0,
     ici_bw=186e9)  # 4-link ICI, ~186 GB/s aggregate per chip
 
+# The one hardware table, keyed by ``jax.devices()[0].device_kind``. The
+# "cpu" row is NOT a CPU: a CPU run stands in for the paper's modelled
+# A100-40GB, so CPU digests and baselines keep that accounting target.
+HARDWARE_BY_DEVICE_KIND = {
+    "TPU v5 lite": TPU_V5E,
+    "cpu": A100_40GB,
+}
+
+
+def hardware_for(device_kind: str) -> HardwareSpec:
+    """The HardwareSpec that prices carbon on ``device_kind``; a device
+    missing from the table is an error, never a silent default."""
+    try:
+        return HARDWARE_BY_DEVICE_KIND[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no HardwareSpec for device kind {device_kind!r}; known: "
+            f"{sorted(HARDWARE_BY_DEVICE_KIND)}") from None
+
 
 @dataclasses.dataclass(frozen=True)
 class ModelProfile:

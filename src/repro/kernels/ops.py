@@ -13,9 +13,11 @@ the env var only redirects callers that asked for "auto".
 """
 from __future__ import annotations
 
+import functools
 import os
 
 import jax
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention as _flash
@@ -68,14 +70,32 @@ def paged_attention(q, k_pages, v_pages, block_table, lengths,
     (native lowering), "pallas_interpret" (kernel semantics on CPU — how
     the tier-1 tests exercise the real kernel), or "xla" (the pure-jnp
     ``kernels/ref.py`` oracle, the serving engine's CPU fast path).
+
+    Under a context mesh with a ``model`` axis (a tensor-parallel engine
+    traces its programs inside ``jax.set_mesh``) the kernel runs per chip
+    under ``jax.shard_map``: GSPMD cannot partition a Mosaic kernel. Each
+    chip attends with its slice of KV heads and their query groups; where
+    the KV heads do not divide the axis, every chip attends over all heads.
     """
     impl = resolve_impl(impl)
     if impl == "xla":
         return ref.paged_attention_ref(q, k_pages, v_pages, block_table,
                                        lengths, k_scale, v_scale)
     assert impl in ("pallas", "pallas_interpret"), impl
-    return _paged(q, k_pages, v_pages, block_table, lengths, k_scale,
-                  v_scale, interpret=impl == "pallas_interpret")
+    kernel = functools.partial(_paged, interpret=impl == "pallas_interpret")
+    scales = () if k_scale is None else (k_scale, v_scale)
+    mesh = jax.sharding.get_abstract_mesh()
+    tp = 1 if mesh.empty else mesh.shape.get("model", 1)
+    if tp == 1:
+        return kernel(q, k_pages, v_pages, block_table, lengths, *scales)
+    heads = "model" if k_pages.shape[2] % tp == 0 else None
+    q_spec, kv_spec = P(None, heads, None), P(None, None, heads, None)
+    sc_spec = P(None, None, heads)
+    return jax.shard_map(
+        kernel, mesh=mesh, check_vma=False, out_specs=q_spec,
+        in_specs=(q_spec, kv_spec, kv_spec, P(), P())
+        + (sc_spec,) * len(scales),
+    )(q, k_pages, v_pages, block_table, lengths, *scales)
 
 
 def fused_rmsnorm(x, scale, residual=None, *, eps: float = 1e-6):

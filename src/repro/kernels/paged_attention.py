@@ -7,11 +7,17 @@ pages), and the per-slot page list is delivered through *scalar prefetch*
 BlockSpec index_map — the TPU analogue of the GPU kernel's pointer chase,
 resolved at DMA-issue time from SMEM.
 
-Grid: (batch, kv_head, page). Online softmax streams one page per step;
-fp32 (m, l, acc) scratch persists across the page sweep. Pages past the
-slot's length are predicated off with ``pl.when`` (no DMA, no FLOPs).
-Supports an int8-quantized cache via per-token-per-head scales, dequantized
-in VMEM after the DMA (halves decode HBM traffic — the memory-roofline win).
+Grid: (batch, page). One grid step DMAs one whole page — every KV head —
+as a ``(1, page, KVH, D)`` block, whose last two dims equal the store's, as
+the TPU tiling rule requires; a static loop over the KV heads then runs the
+online softmax for each head's query group. fp32 (m, l, acc) scratch is
+``(KVH, G, 1|D)``, so no scratch array is 1-D, and persists across the page
+sweep. Pages past the slot's length are predicated off with ``pl.when``
+(no FLOPs), and their index_map repeats the slot's last live page, so the
+pipeline issues no DMA for them either.
+Supports an int8-quantized cache via per-token-per-head scales
+(``(1, page, KVH)`` blocks), dequantized in VMEM after the DMA (halves
+decode HBM traffic — the memory-roofline win).
 
 Layouts: q (B, H, D); k/v pages (P, page, KVH, D) -> out (B, H, D).
 """
@@ -30,9 +36,9 @@ NEG_INF = -1e30
 
 def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
                   o_ref, m_scr, l_scr, acc_scr, *, page: int, n_pages: int,
-                  group: int, sm_scale: float, quantized: bool):
+                  n_kv: int, sm_scale: float, quantized: bool):
     b = pl.program_id(0)
-    pi = pl.program_id(2)
+    pi = pl.program_id(1)
 
     @pl.when(pi == 0)
     def _init():
@@ -44,34 +50,34 @@ def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
 
     @pl.when(pi * page < length)
     def _body():
-        q = q_ref[0, 0].astype(jnp.float32)             # (G, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)       # (page, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        if quantized:
-            k = k * ks_ref[0, :, 0][:, None]
-            v = v * vs_ref[0, :, 0][:, None]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s = s * sm_scale                                 # (G, page)
-        pos = pi * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(pos < length, s, NEG_INF)
-
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        m_safe = jnp.maximum(m_new, NEG_INF / 2)
-        p = jnp.exp(s - m_safe[:, None])
-        p = jnp.where(pos < length, p, 0.0)
-        corr = jnp.where(m_prev > NEG_INF / 2,
-                         jnp.exp(jnp.maximum(m_prev, NEG_INF / 2) - m_safe), 0.0)
-        l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1)
-        acc_scr[...] = acc_scr[...] * corr[:, None] + jax.lax.dot(
-            p, v, preferred_element_type=jnp.float32)
-        m_scr[...] = m_new
+        for h in range(n_kv):
+            q = q_ref[0, h].astype(jnp.float32)           # (G, D)
+            k = k_ref[0, :, h, :].astype(jnp.float32)     # (page, D)
+            v = v_ref[0, :, h, :].astype(jnp.float32)
+            if quantized:
+                k = k * ks_ref[0, :, h:h + 1]
+                v = v * vs_ref[0, :, h:h + 1]
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = s * sm_scale                               # (G, page)
+            pos = pi * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            valid = pos < length
+            s = jnp.where(valid, s, NEG_INF)
+            # this page holds at least one valid position, so m_new is
+            # finite; the first page's corr is exp(NEG_INF - m_new) = 0
+            m_prev = m_scr[h]                              # (G, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+            corr = jnp.exp(m_prev - m_new)
+            l_scr[h] = l_scr[h] * corr + jnp.sum(p, axis=-1, keepdims=True)
+            acc_scr[h] = acc_scr[h] * corr + jax.lax.dot(
+                p, v, preferred_element_type=jnp.float32)
+            m_scr[h] = m_new
 
     @pl.when(pi == n_pages - 1)
     def _finalize():
         l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, 0] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -83,46 +89,49 @@ def paged_attention(q, k_pages, v_pages, block_table, lengths,
     P, page, KVH, _ = k_pages.shape
     max_pages = block_table.shape[1]
     group = H // KVH
-    sm_scale = 1.0 / math.sqrt(D)
     quantized = k_scale is not None
-    if not quantized:  # dummy scale operands keep one kernel signature
-        k_scale = jnp.ones((P, page, KVH), jnp.float32)
-        v_scale = jnp.ones((P, page, KVH), jnp.float32)
+    if not quantized:
+        # one dummy scale block keeps one kernel signature; its index is
+        # constant, so it is fetched once per call
+        k_scale = v_scale = jnp.ones((1, page, KVH), jnp.float32)
 
-    # q reorganized to (B, KVH, G, D) so one grid step owns one kv head
+    # q reorganized to (B, KVH, G, D): one block holds a slot's every head
     qr = q.reshape(B, KVH, group, D)
 
-    def q_map(b, kvh, pi, bt, ln):
-        return (b, kvh, 0, 0)
+    def page_of(b, pi, bt, ln):
+        # steps past the slot's last live page revisit that page: the
+        # block index does not change, so the pipeline skips the DMA
+        last = jnp.maximum(ln[b] - 1, 0) // page
+        return bt[b, jnp.minimum(pi, last)]
 
-    def kv_map(b, kvh, pi, bt, ln):
-        return (bt[b, pi], 0, kvh, 0)
+    def q_map(b, pi, bt, ln):
+        return (b, 0, 0, 0)
 
-    def sc_map(b, kvh, pi, bt, ln):
-        return (bt[b, pi], 0, kvh)
+    def kv_map(b, pi, bt, ln):
+        return (page_of(b, pi, bt, ln), 0, 0, 0)
 
-    def o_map(b, kvh, pi, bt, ln):
-        return (b, kvh, 0, 0)
+    def sc_map(b, pi, bt, ln):
+        return (page_of(b, pi, bt, ln), 0, 0) if quantized else (0, 0, 0)
 
     kernel = functools.partial(
-        _paged_kernel, page=page, n_pages=max_pages, group=group,
-        sm_scale=sm_scale, quantized=quantized)
+        _paged_kernel, page=page, n_pages=max_pages, n_kv=KVH,
+        sm_scale=1.0 / math.sqrt(D), quantized=quantized)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, KVH, max_pages),
+        grid=(B, max_pages),
         in_specs=[
-            pl.BlockSpec((1, 1, group, D), q_map),
-            pl.BlockSpec((1, page, 1, D), kv_map),
-            pl.BlockSpec((1, page, 1, D), kv_map),
-            pl.BlockSpec((1, page, 1), sc_map),
-            pl.BlockSpec((1, page, 1), sc_map),
+            pl.BlockSpec((1, KVH, group, D), q_map),
+            pl.BlockSpec((1, page, KVH, D), kv_map),
+            pl.BlockSpec((1, page, KVH, D), kv_map),
+            pl.BlockSpec((1, page, KVH), sc_map),
+            pl.BlockSpec((1, page, KVH), sc_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, group, D), o_map),
+        out_specs=pl.BlockSpec((1, KVH, group, D), q_map),
         scratch_shapes=[
-            pltpu.VMEM((group,), jnp.float32),
-            pltpu.VMEM((group,), jnp.float32),
-            pltpu.VMEM((group, D), jnp.float32),
+            pltpu.VMEM((KVH, group, 1), jnp.float32),
+            pltpu.VMEM((KVH, group, 1), jnp.float32),
+            pltpu.VMEM((KVH, group, D), jnp.float32),
         ],
     )
 
@@ -130,6 +139,9 @@ def paged_attention(q, k_pages, v_pages, block_table, lengths,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KVH, group, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        name="paged_attention",
         interpret=interpret,
     )(jnp.clip(block_table, 0, P - 1), lengths.astype(jnp.int32),
       qr, k_pages, v_pages, k_scale, v_scale)

@@ -1,70 +1,23 @@
-"""Production mesh builders + jax-version compatibility shims.
+"""Production mesh builders.
 
 A FUNCTION, not a module-level constant — importing this module must never
 touch jax device state (the dry-run pins the device count before any jax
 call; tests and benches must keep seeing 1 CPU device).
 
-The compat layer papers over the two API moves between jax 0.4.x and
-jax >= 0.5 that the sharding/training suites (and the serving engine's
-tensor-parallel path, DESIGN.md §14) depend on:
-
-* ``AbstractMesh`` — 0.4.x takes ``((name, size), ...)`` shape tuples,
-  >= 0.5 takes ``(axis_sizes, axis_names)``. ``abstract_mesh`` accepts the
-  new-style arguments on both.
-* ``shard_map`` — >= 0.5 exports it at top level with ``check_vma``;
-  0.4.x only has ``jax.experimental.shard_map.shard_map`` with
-  ``check_rep``. ``shard_map_compat`` maps one onto the other.
+Every mesh is built with ``AxisType.Auto`` axes: ``jax.make_mesh`` defaults
+to explicit-sharding axes, under which the unchanged model code would have
+to annotate every gather and matmul. Auto axes leave placement to GSPMD
+propagation from the parameter and cache shardings (DESIGN.md §14).
 """
 from __future__ import annotations
 
 import jax
-from jax.sharding import AbstractMesh
+from jax.sharding import AxisType
 
 
-def abstract_mesh(axis_sizes, axis_names) -> AbstractMesh:
-    """``AbstractMesh(axis_sizes, axis_names)`` on every supported jax.
-
-    Tries the jax >= 0.5 signature first; on 0.4.x (TypeError: sizes are
-    not iterable pairs) falls back to the old ``((name, size), ...)``
-    shape-tuple form. Either way the returned mesh answers
-    ``mesh.shape[name]`` / ``mesh.axis_names`` identically and is a valid
-    ``NamedSharding`` mesh argument."""
-    axis_sizes = tuple(int(s) for s in axis_sizes)
-    axis_names = tuple(axis_names)
-    try:
-        return AbstractMesh(axis_sizes, axis_names)
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
-
-
-def shard_map_supported() -> bool:
-    """True when the installed jax exports top-level ``jax.shard_map``
-    (the >= 0.5 API). The serving engine uses this to pick its TP
-    mechanism: shard_map where available, jit-with-NamedSharding
-    constraints otherwise (DESIGN.md §14)."""
-    return hasattr(jax, "shard_map")
-
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """``jax.shard_map`` with the >= 0.5 keyword surface on every jax.
-
-    On 0.4.x this forwards to ``jax.experimental.shard_map.shard_map``,
-    translating ``check_vma`` to its older ``check_rep`` spelling."""
-    if shard_map_supported():
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
-
-
-def axis_size(axis_name):
-    """``jax.lax.axis_size`` on every supported jax. 0.4.x lacks the
-    function; the classic psum-of-one idiom computes the same trace-time
-    constant inside any mapped context (shard_map/pmap)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
+def _auto_mesh(shape, axes, devices):
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_tp_mesh(tp_degree: int):
@@ -82,8 +35,8 @@ def make_tp_mesh(tp_degree: int):
             f"{jax.device_count()}; on CPU set XLA_FLAGS="
             f"--xla_force_host_platform_device_count={tp_degree} (or more) "
             f"before importing jax")
-    return jax.make_mesh((1, tp_degree), ("data", "model"),
-                         devices=jax.devices()[:tp_degree])
+    return _auto_mesh((1, tp_degree), ("data", "model"),
+                      jax.devices()[:tp_degree])
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -97,7 +50,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     n = 1
     for s in shape:
         n *= s
-    return jax.make_mesh(shape, axes, devices=jax.devices()[:n])
+    return _auto_mesh(shape, axes, jax.devices()[:n])
 
 
 def batch_axes(multi_pod: bool = False):

@@ -3,10 +3,10 @@
     PYTHONPATH=src python -m repro.launch.serve --arch llama2_13b \
         --region CA --replicas 2 --requests 24
 
-Runs the real continuous-batching engine on the reduced config (CPU
-container) with the full SPROUT control plane: hourly LP re-planning from
-the regional carbon-intensity trace, directive rendering into system
-prompts, level-cost profiling, and preemption-safe scheduling.
+Runs the real continuous-batching engine with the full SPROUT control
+plane: hourly LP re-planning from the regional carbon-intensity trace,
+directive rendering into system prompts, level-cost profiling, and
+preemption-safe scheduling.
 
 ``--gateway`` switches to the closed-loop SproutGateway: one scheduler
 pool per ``--regions`` entry, the LP re-planned per pool from its live
@@ -19,6 +19,11 @@ their TTFT/TPOT latency targets so admission routes on predicted
 completion time jointly with greenness; ``--drain-at H`` empties the
 ``--drain-region`` pool ahead of maintenance at hour H (DESIGN.md §10).
 
+``--config published`` serves the architecture at its published widths
+(weights drawn from a seed) instead of the default reduced CPU config;
+``--max-len`` sizes each slot's KV region. Carbon is priced on the
+HardwareSpec of the device jax reports (``core.energy.hardware_for``).
+
 ``--chaos`` arms the default fault-injection script (DESIGN.md §12) — one
 fault of every class against the first pool — and prints the recovery
 counters; ``--grid-provider electricitymaps --grid-token ...`` swaps the
@@ -30,14 +35,16 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import math
+import os
+import pathlib
 
 import jax
 import numpy as np
 
-from repro.configs import reduced
-from repro.core import (A100_40GB, DEFAULT_TENANTS, LLAMA2_13B,
-                        CarbonIntensityProvider, DirectiveSet, EnergyModel,
-                        GridSignalClient, QualityEvaluator, Workload,
+from repro.configs import get_config, reduced
+from repro.core import (DEFAULT_TENANTS, LLAMA2_13B, CarbonIntensityProvider,
+                        DirectiveSet, EnergyModel, GridSignalClient,
+                        QualityEvaluator, Workload, hardware_for,
                         solve_directive_lp)
 from repro.core.carbon import WatchdogProvider
 from repro.core.policies import LevelProfiles, SproutPolicy
@@ -46,6 +53,45 @@ from repro.serving import (CarbonAwareScheduler, FaultInjector, FaultPlan,
                            FaultSpec, InferenceEngine, MigrationPlanner,
                            ServeRequest, SproutGateway, no_faults,
                            serve_request_from)
+
+# the persistent compile cache lives at a fixed path inside the checkout:
+# the path is part of the cache key, so a directory that moves never hits
+COMPILE_CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def setup_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache on an accelerator and
+    return its directory ("" where it stays off). Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and nothing
+    else is set; otherwise the cache goes to ``COMPILE_CACHE_DIR``. On the
+    CPU it stays off: XLA:CPU logs a machine-feature mismatch error on
+    every entry it reloads. Call before the first compile, never at
+    import time."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    if jax.default_backend() == "cpu":
+        return ""
+    jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
+    return str(COMPILE_CACHE_DIR)
+
+
+# published widths: each weight is drawn and cast inside one program, so no
+# float32 copy of the whole model is ever materialized
+_init_model_jit = jax.jit(MD.init_model, static_argnums=0)
+
+
+def load_model(arch: str, config: str, seed: int = 0):
+    """(cfg, params) for ``arch``: ``reduced`` is the CPU-sized config with
+    a 512-token vocabulary; ``published`` is the architecture at its
+    published widths. Weights are random, drawn from ``seed``."""
+    key = jax.random.PRNGKey(seed)
+    if config == "published":
+        cfg = get_config(arch)
+        return cfg, _init_model_jit(cfg, key)
+    cfg = reduced(arch).replace(vocab_size=512)
+    return cfg, MD.init_model(cfg, key)
+
 
 # request mix across service classes for --tenants runs (premium is the
 # minority class with the hard floor; batch soaks up the brief levels)
@@ -87,8 +133,9 @@ def grid_provider(region: str, args) -> CarbonIntensityProvider:
                             token=args.grid_token)
 
 
-def run_gateway(args, cfg, params) -> None:
-    """Closed-loop mode: LP -> scheduler pools -> engine telemetry -> LP."""
+def run_gateway(args, cfg, params, hw) -> SproutGateway:
+    """Closed-loop mode: LP -> scheduler pools -> engine telemetry -> LP.
+    Returns the gateway, whose stats hold what was served."""
     regions = [r.strip() for r in args.regions.split(",") if r.strip()]
     workload = Workload(seed=0)
     evaluator = QualityEvaluator(sample_size=200)
@@ -107,7 +154,8 @@ def run_gateway(args, cfg, params) -> None:
             # eos_id=-1: the tiny random model has no meaningful EOS, so
             # decoding is budget-bound and measured token counts carry the
             # per-level brevity structure
-            InferenceEngine(cfg, params, n_slots=args.slots, max_len=96,
+            InferenceEngine(cfg, params, n_slots=args.slots,
+                            max_len=args.max_len,
                             seed=100 * j + i, decode_block=args.decode_block,
                             eos_id=-1, **engine_kv_kwargs(args))
             for i in range(args.replicas)]
@@ -119,14 +167,14 @@ def run_gateway(args, cfg, params) -> None:
     # --tenants, so don't build one that would be silently ignored
     policy = None if tenants else SproutPolicy(
         k0_min=k_min, k0_max=k_max, xi=args.xi,
-        k1=A100_40GB.embodied_gco2 / A100_40GB.lifetime_s)
+        k1=hw.embodied_gco2 / hw.lifetime_s)
     # the accounting profile mirrors the engine's KV dtype, so the int8
     # flag halves modeled decode KV bytes end to end (roofline -> level
     # profiles -> LP -> Eq. 1 carbon)
     profile = LLAMA2_13B.with_int8_kv() if args.kv_int8 else LLAMA2_13B
     migration = MigrationPlanner() if args.migrate else None
     gw = SproutGateway(pools, policy=policy, tenants=tenants,
-                       energy=EnergyModel(A100_40GB),
+                       energy=EnergyModel(hw), hw=hw,
                        model_profile=profile, load_cap=args.load_cap,
                        forecast_horizon=args.forecast_horizon,
                        migration=migration, fault_injector=injector)
@@ -195,6 +243,7 @@ def run_gateway(args, cfg, params) -> None:
                        for name in ("premium", "standard", "batch"))
         print(f"slo attainment: {att}")
     print(f"profiled e (kWh/level): {np.round(gw.profiles.e, 9)}")
+    return gw
 
 
 def engine_kv_kwargs(args) -> dict:
@@ -209,9 +258,18 @@ def engine_kv_kwargs(args) -> dict:
     return kw
 
 
-def main() -> None:
+def main(argv=None):
+    """Run the service; returns the gateway (``--gateway``) or the
+    scheduler, so an in-process caller can inspect what was served."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama2_13b")
+    ap.add_argument("--config", default="reduced",
+                    choices=("reduced", "published"),
+                    help="reduced CPU-sized config (vocab 512) or the "
+                         "architecture's published widths; weights are "
+                         "random either way")
+    ap.add_argument("--max-len", type=int, default=96,
+                    help="KV region per slot, in tokens (prompt + output)")
     ap.add_argument("--region", default="CA")
     ap.add_argument("--replicas", type=int, default=2)
     ap.add_argument("--slots", type=int, default=2)
@@ -299,17 +357,17 @@ def main() -> None:
                          "requests with copy-on-write on divergence — "
                          "cached prompt spans skip prefill entirely and "
                          "Eq. 1 accounting credits the skipped tokens")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.slo:
         args.tenants = True
 
-    cfg = reduced(args.arch).replace(vocab_size=512)
-    params = MD.init_model(cfg, jax.random.PRNGKey(0))
+    setup_compile_cache()
+    hw = hardware_for(jax.devices()[0].device_kind)
+    cfg, params = load_model(args.arch, args.config)
     if args.gateway:
-        run_gateway(args, cfg, params)
-        return
+        return run_gateway(args, cfg, params, hw)
     grid = CarbonIntensityProvider(args.region, "jun")
-    energy = EnergyModel(A100_40GB)
+    energy = EnergyModel(hw)
     profile = LLAMA2_13B.with_int8_kv() if args.kv_int8 else LLAMA2_13B
     directives = DirectiveSet()
     profiles = LevelProfiles.fresh()
@@ -320,7 +378,8 @@ def main() -> None:
     plan = {"x": np.ones(3) / 3}
 
     sched = CarbonAwareScheduler(
-        [InferenceEngine(cfg, params, n_slots=args.slots, max_len=96, seed=i,
+        [InferenceEngine(cfg, params, n_slots=args.slots,
+                         max_len=args.max_len, seed=i,
                          decode_block=args.decode_block,
                          **engine_kv_kwargs(args))
          for i in range(args.replicas)],
@@ -333,7 +392,7 @@ def main() -> None:
         if profiles.counts.min() >= 2:
             sol = solve_directive_lp(
                 profiles.e, profiles.p, q, k0=k0,
-                k1=A100_40GB.embodied_gco2 / A100_40GB.lifetime_s,
+                k1=hw.embodied_gco2 / hw.lifetime_s,
                 k0_min=grid.k_min, k0_max=grid.k_max, xi=args.xi)
             plan["x"] = sol.x
         pool = [workload.sample_request(hour + i * 0.01) for i in range(300)]
@@ -355,6 +414,7 @@ def main() -> None:
         print(f"rejected rid={req.rid}: {reason}", flush=True)
     print(f"total (13B-scale estimate): {total_g:.3f} gCO2 "
           f"across {served} requests")
+    return sched
 
 
 if __name__ == "__main__":

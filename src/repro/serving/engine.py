@@ -80,6 +80,7 @@ never per adopter.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from typing import Callable, Dict, List, Optional, Tuple
@@ -984,8 +985,12 @@ class InferenceEngine:
             state["chunk_lane"] = jnp.asarray(lane_pos, jnp.int32)
         fn, warm = self._fused_for(k, mode, bs, chunk_c)
         t_dec = time.monotonic()
-        self.cache, toks, valid, live_dev, ok_dev = fn(
-            self.params, self.cache, block_table, state, chunk_xs)
+        # a tp engine traces under its mesh, so the paged kernel (which
+        # GSPMD cannot partition) finds the mesh to shard_map over
+        with (jax.set_mesh(self.shard_spec.mesh) if self.shard_spec
+              else contextlib.nullcontext()):
+            self.cache, toks, valid, live_dev, ok_dev = fn(
+                self.params, self.cache, block_table, state, chunk_xs)
         # sproutlint: allow(SPL001) — the single host<->device sync for
         # this block of <= k*bs tokens; the per-lane finiteness verdict
         # rides the SAME fetch (no extra sync for fault detection); budget

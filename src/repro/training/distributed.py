@@ -26,7 +26,6 @@ from typing import Any, List, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.launch.mesh import axis_size
 import numpy as np
 
 PyTree = Any
@@ -88,7 +87,7 @@ def compressed_psum(tree: PyTree, axis_name: str,
     chunk j to device j (all_to_all, int8), locally dequantizes + sums its
     owned chunk in fp32, requantizes, and all-gathers the int8 result.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     leaves, tdef, sizes, buckets = _bucket_layout(tree, bucket_bytes)
     out: List[Optional[jnp.ndarray]] = [None] * len(leaves)
     for idxs in buckets:
@@ -132,12 +131,12 @@ def periodic_sync(tree: PyTree, axis_name: str, step, every: int,
     do = (step % every) == 0
 
     def mean_branch(t):
-        n = axis_size(axis_name)
+        n = jax.lax.axis_size(axis_name)
         return jax.tree.map(lambda x: x / n, sync(t))
 
     return jax.lax.cond(do, mean_branch, lambda t: t, tree)
 
 
 def pmean(tree: PyTree, axis_name: str) -> PyTree:
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     return jax.tree.map(lambda x: x / n, bucketed_psum(tree, axis_name))

@@ -18,7 +18,6 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 
-from repro.launch.mesh import axis_size
 
 PyTree = Any
 
@@ -34,7 +33,7 @@ def pipeline_apply(fn: Callable, stage_params: PyTree, microbatches,
     Returns (M, ...) outputs (replicated across stages after the final
     collect).
     """
-    S = axis_size(axis_name)
+    S = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     M = microbatches.shape[0]
     T = M + S - 1

@@ -9,7 +9,8 @@ from hypothesis import given, strategies as st
 
 from repro.core.carbon import (REGIONS, SEASONS, carbon_intensity_trace,
                                request_carbon)
-from repro.core.energy import A100_40GB, LLAMA2_7B, LLAMA2_13B, EnergyModel
+from repro.core.energy import (A100_40GB, LLAMA2_7B, LLAMA2_13B, TPU_V5E,
+                               EnergyModel, hardware_for)
 from repro.core.workload import N_LEVELS, TASKS, Workload
 
 
@@ -80,6 +81,19 @@ def test_energy_model_paper_anchors():
     r = em.request_energy_kwh(LLAMA2_13B, 100, 200) / \
         em.request_energy_kwh(LLAMA2_7B, 100, 200)
     assert 1.4 < r < 2.3
+
+
+@pytest.mark.parametrize("kind,spec", [("TPU v5 lite", TPU_V5E),
+                                       ("cpu", A100_40GB)])
+def test_hardware_for_known_device_kinds(kind, spec):
+    # a CPU run keeps pricing the paper's modelled A100, so CPU digests
+    # and baselines do not move
+    assert hardware_for(kind) is spec
+
+
+def test_hardware_for_unknown_device_kind_raises():
+    with pytest.raises(ValueError, match="no HardwareSpec"):
+        hardware_for("TPU v4")
 
 
 def test_workload_request_structure():

@@ -18,12 +18,11 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp, numpy as np
 from functools import partial
-from jax.sharding import PartitionSpec as P
-from repro.launch.mesh import shard_map_compat as shard_map
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.training.pipeline import pipeline_apply
 
 S, M, B, D = 4, 8, 2, 16
-mesh = jax.make_mesh((S,), ("stage",))
+mesh = jax.make_mesh((S,), ("stage",), axis_types=(AxisType.Auto,))
 key = jax.random.PRNGKey(0)
 W = jax.random.normal(key, (S, D, D)) * 0.3
 b = jax.random.normal(jax.random.PRNGKey(1), (S, D)) * 0.1
@@ -33,7 +32,7 @@ def stage_fn(params, x):
     w, bias = params
     return jnp.tanh(x @ w + bias)
 
-@partial(shard_map, mesh=mesh, in_specs=(P("stage"), P(None)),
+@partial(jax.shard_map, mesh=mesh, in_specs=(P("stage"), P(None)),
          out_specs=P(None), check_vma=False)
 def piped(params, mbs):
     w, bias = params
@@ -51,12 +50,10 @@ print("PIPELINE_OK")
 
 
 def test_pipeline_matches_sequential_subprocess():
-    # the snippet goes through shard_map_compat (launch/mesh.py), which
-    # maps the jax>=0.5 check_vma keyword onto 0.4.x check_rep — this was
-    # an xfail from PR 4 to PR 9 (DESIGN.md §9). JAX_PLATFORMS stays
-    # pinned to cpu: an unpinned jax probes for TPU hardware and spends
-    # minutes in metadata-fetch retries on CPU-only containers, while the
-    # forced host device count only applies to the CPU platform anyway.
+    # JAX_PLATFORMS stays pinned to cpu: an unpinned jax probes for TPU
+    # hardware and spends minutes in metadata-fetch retries on CPU-only
+    # containers, while the forced host device count only applies to the
+    # CPU platform anyway.
     env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     r = subprocess.run([sys.executable, "-c", _SNIPPET], env=env,
                        capture_output=True, text=True, timeout=420,

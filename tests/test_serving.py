@@ -1,5 +1,7 @@
 """Serving stack: engine, continuous batching, failover, paged cache,
 tokenizer round-trips, sampler."""
+import pathlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -328,3 +330,31 @@ def test_sampler_batched_mixed_greedy_and_sampled():
         jnp.asarray([1.0, 1.0, 1.0, 0.6])))
     assert out[0] == 1 and out[2] == 1          # greedy rows
     assert out[1] in (1, 2) and out[3] == 1     # top-k=2 / top-p=0.6 rows
+
+
+def test_launcher_main_serves_in_process():
+    from repro.launch import serve
+    gw = serve.main(["--arch", "granite_3_2b", "--gateway", "--paged",
+                     "--page-size", "16", "--max-len", "128", "--hours", "1",
+                     "--requests", "2", "--replicas", "1", "--max-new", "8"])
+    assert gw.stats.requests == 2 and gw.stats.rejected == 0
+    assert gw.pools[0].scheduler.engines[0].max_len == 128
+
+
+def test_compile_cache_dir_from_env_or_fixed_path(monkeypatch):
+    from repro.launch import serve
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+    assert serve.setup_compile_cache() == "/elsewhere/cache"
+    assert jax.config.jax_compilation_cache_dir == before  # left to jax
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    assert serve.setup_compile_cache() == ""           # off on the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    try:
+        want = str(serve.COMPILE_CACHE_DIR)
+        assert serve.setup_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+        repo = pathlib.Path(__file__).resolve().parents[1]
+        assert serve.COMPILE_CACHE_DIR == repo / ".jax_cache"
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -3,23 +3,20 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AbstractMesh
 
 from repro.configs import ASSIGNED, get_config
 from repro.launch import hlo_cost as HC
 from repro.launch import roofline as RL
 from repro.launch import sharding as SH
-from repro.launch.mesh import abstract_mesh
 from repro.launch.shapes import SHAPES, SHAPE_BY_NAME, input_specs, skip_reason
 from repro.models import model as MD
 
 
 def _mesh(multi=False):
-    # abstract_mesh: the jax-version compat constructor (launch/mesh.py) --
-    # these 26 cases were xfail'd from PR 4 to PR 9 because jax 0.4.x
-    # cannot construct AbstractMesh from (axis_sizes, axis_names) directly
     if multi:
-        return abstract_mesh((2, 16, 16), ("pod", "data", "model"))
-    return abstract_mesh((16, 16), ("data", "model"))
+        return AbstractMesh((2, 16, 16), ("pod", "data", "model"))
+    return AbstractMesh((16, 16), ("data", "model"))
 
 
 def _axis_size(mesh, axis):

@@ -85,6 +85,26 @@ def test_tp4_token_identical(small_model, paged, sampling):
     assert got == ref
 
 
+@_needs(4)
+@pytest.mark.parametrize("tp,kv_int8", [(2, False), (4, True)],
+                         ids=["tp2-fp32", "tp4-int8"])
+def test_tp_pallas_paged_kernel_token_identical(small_model, tp, kv_int8):
+    # the Pallas paged kernel cannot be partitioned by GSPMD, so a tp
+    # engine runs it under shard_map (kernels/ops.py): tp=2 gives each
+    # device one of the 2 KV heads, tp=4 does not divide them and every
+    # device attends over both. Interpret mode runs the kernel's own code.
+    cfg, params = small_model
+
+    def run(t):
+        eng = InferenceEngine(cfg, params, n_slots=4, max_len=64, eos_id=-1,
+                              seed=7, paged=True, page_size=16,
+                              kv_int8=kv_int8, tp_degree=t, prefill_chunk=8,
+                              paged_impl="pallas_interpret")
+        return _decode_all(eng)
+
+    assert run(tp) == run(1)
+
+
 @_needs(2)
 def test_tp_engine_reports_degree(small_model):
     eng = _engine(small_model, 2)

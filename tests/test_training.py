@@ -96,31 +96,30 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 from functools import partial
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import PartitionSpec as P
-from repro.launch.mesh import shard_map_compat as shard_map
+from jax.sharding import AxisType, PartitionSpec as P
 from repro.training import distributed
 
-mesh = jax.make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
 g = {"a": jax.random.normal(jax.random.PRNGKey(1), (8, 64)),
      "b": jax.random.normal(jax.random.PRNGKey(2), (8, 33))}
 exact = jax.tree.map(lambda x: jnp.broadcast_to(x.sum(0, keepdims=True), x.shape), g)
 
-f1 = shard_map(lambda t: distributed.bucketed_psum(t, "data"),
-               mesh=mesh, in_specs=P("data"), out_specs=P("data"), check_vma=False)
+f1 = jax.shard_map(lambda t: distributed.bucketed_psum(t, "data"),
+                   mesh=mesh, in_specs=P("data"), out_specs=P("data"), check_vma=False)
 r1 = f1(g)
 for k in g:
     np.testing.assert_allclose(np.asarray(r1[k]), np.asarray(exact[k]), rtol=1e-5, atol=1e-5)
 
-f2 = shard_map(lambda t: distributed.compressed_psum(t, "data"),
-               mesh=mesh, in_specs=P("data"), out_specs=P("data"), check_vma=False)
+f2 = jax.shard_map(lambda t: distributed.compressed_psum(t, "data"),
+                   mesh=mesh, in_specs=P("data"), out_specs=P("data"), check_vma=False)
 r2 = f2(g)
 for k in g:
     rel = float(jnp.max(jnp.abs(r2[k]-exact[k]))) / float(jnp.max(jnp.abs(exact[k])))
     assert rel < 0.05, rel
 
 def per_step(step):
-    f3 = shard_map(lambda t: distributed.periodic_sync(t, "data", step, 4),
-                   mesh=mesh, in_specs=P("data"), out_specs=P("data"), check_vma=False)
+    f3 = jax.shard_map(lambda t: distributed.periodic_sync(t, "data", step, 4),
+                       mesh=mesh, in_specs=P("data"), out_specs=P("data"), check_vma=False)
     return f3(g)
 synced = per_step(8)     # 8 % 4 == 0 -> mean across axis
 local = per_step(9)      # no sync
@@ -132,9 +131,8 @@ print("DIST_OK")
 
 
 def test_distributed_primitives_subprocess():
-    # the snippet goes through shard_map_compat (launch/mesh.py), which
-    # maps the jax>=0.5 check_vma keyword onto 0.4.x check_rep — this was
-    # an xfail from PR 4 to PR 9 (DESIGN.md §9). JAX_PLATFORMS must stay
+    # the snippet's mesh uses Auto axes: under jax.make_mesh's default
+    # explicit axes the collectives fail to type-check. JAX_PLATFORMS must stay
     # pinned to cpu: an unpinned jax probes for TPU hardware and spends
     # minutes in metadata-fetch retries on CPU-only containers, while the
     # forced host device count only applies to the CPU platform anyway.
