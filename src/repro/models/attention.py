@@ -298,14 +298,15 @@ def gqa_chunk_append(cfg: ModelConfig, p, x, positions, valid, lane, cache,
         def wr(buf, val):          # buf (B, S, ...), val (1, C, ...)
             return buf.at[lane, idx].set(val[0].astype(buf.dtype))
 
-        if quantized:
-            cache = dict(cache, k=wr(cache["k"], qk), v=wr(cache["v"], qv),
-                         k_scale=wr(cache["k_scale"], sk),
-                         v_scale=wr(cache["v_scale"], sv))
-        else:
-            cache = dict(cache, k=wr(cache["k"], k_new),
-                         v=wr(cache["v"], v_new))
-        cache["kpos"] = cache["kpos"].at[lane, idx].set(positions)
+        with jax.named_scope("sprout.kv_write"):
+            if quantized:
+                cache = dict(cache, k=wr(cache["k"], qk), v=wr(cache["v"], qv),
+                             k_scale=wr(cache["k_scale"], sk),
+                             v_scale=wr(cache["v_scale"], sv))
+            else:
+                cache = dict(cache, k=wr(cache["k"], k_new),
+                             v=wr(cache["v"], v_new))
+            cache["kpos"] = cache["kpos"].at[lane, idx].set(positions)
         if quantized:
             k = dequantize_kv(cache["k"][lane], cache["k_scale"][lane],
                               cfg.compute_dtype)[None]
@@ -327,13 +328,14 @@ def gqa_chunk_append(cfg: ModelConfig, p, x, positions, valid, lane, cache,
         def wr(buf, val):          # buf (P, ps, ...), val (1, C, ...)
             return buf.at[page, off].set(val[0].astype(buf.dtype))
 
-        if quantized:
-            cache = dict(cache, k=wr(cache["k"], qk), v=wr(cache["v"], qv),
-                         k_scale=wr(cache["k_scale"], sk),
-                         v_scale=wr(cache["v_scale"], sv))
-        else:
-            cache = dict(cache, k=wr(cache["k"], k_new),
-                         v=wr(cache["v"], v_new))
+        with jax.named_scope("sprout.kv_write"):
+            if quantized:
+                cache = dict(cache, k=wr(cache["k"], qk), v=wr(cache["v"], qv),
+                             k_scale=wr(cache["k_scale"], sk),
+                             v_scale=wr(cache["v_scale"], sv))
+            else:
+                cache = dict(cache, k=wr(cache["k"], k_new),
+                             v=wr(cache["v"], v_new))
         # gather the lane's pages (clamped; stale/unmapped rows sit at
         # absolute indices > q_pos and are masked positionally)
         safe = jnp.clip(bt, 0, P - 1)
@@ -423,8 +425,9 @@ def gqa_decode_paged(cfg: ModelConfig, p, x, positions, cache, block_table,
     h, dh = cfg.n_heads, cfg.head_dim
     quantized = cfg.kv_cache_dtype == "int8"
     q, k_new, v_new = _project_qkv(cfg, p, x, positions[:, None])
-    cache = _paged_cache_write(cache, k_new, v_new, positions, block_table,
-                               live, quantized)
+    with jax.named_scope("sprout.kv_write"):
+        cache = _paged_cache_write(cache, k_new, v_new, positions,
+                                   block_table, live, quantized)
     # append-only pages: validity == index < length, causality is implicit
     lengths = positions + 1
     out = ops.paged_attention(

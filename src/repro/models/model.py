@@ -260,42 +260,43 @@ def block_apply(cfg: ModelConfig, kind: str, p, x, positions, mode: str,
     h = apply_norm(cfg, p["ln1"], x)
     causal = kind != "enc"
     new_attn_cache = None
-    if mode == "decode":
-        if block_table is not None:
-            a, new_attn_cache = A.gqa_decode_paged(
-                cfg, p["attn"], h, positions, cache, block_table,
-                live=live, impl=paged_impl)
-        elif is_mla:
-            a, new_attn_cache = A.mla_decode(cfg, p["attn"], h, positions, cache
-                                             if kind in ("dense", "moe") else cache["attn"])
+    with jax.named_scope("sprout.attention"):
+        if mode == "decode":
+            if block_table is not None:
+                a, new_attn_cache = A.gqa_decode_paged(
+                    cfg, p["attn"], h, positions, cache, block_table,
+                    live=live, impl=paged_impl)
+            elif is_mla:
+                a, new_attn_cache = A.mla_decode(cfg, p["attn"], h, positions, cache
+                                                 if kind in ("dense", "moe") else cache["attn"])
+            else:
+                c_in = (cache["self"] if kind == "xdec"
+                        else cache["attn"] if kind.startswith("hyb") else cache)
+                a, new_attn_cache = A.gqa_decode(cfg, p["attn"], h, positions,
+                                                 c_in, window=window)
         else:
-            c_in = (cache["self"] if kind == "xdec"
-                    else cache["attn"] if kind.startswith("hyb") else cache)
-            a, new_attn_cache = A.gqa_decode(cfg, p["attn"], h, positions,
-                                             c_in, window=window)
-    else:
-        if is_mla:
-            a, (ckv, kr) = A.mla_full(cfg, p["attn"], h, positions, causal=causal)
-            if mode == "prefill":
-                T = h.shape[1]
-                c0 = A.init_mla_cache(cfg, B, max_len)
-                kp = kpos_of(T)
-                c0 = dict(c0,
-                          ckv=jax.lax.dynamic_update_slice_in_dim(
-                              c0["ckv"], ckv.astype(c0["ckv"].dtype), 0, 1),
-                          kr=jax.lax.dynamic_update_slice_in_dim(
-                              c0["kr"], kr.astype(c0["kr"].dtype), 0, 1),
-                          kpos=jax.lax.dynamic_update_slice_in_dim(c0["kpos"], kp, 0, 1))
-                new_attn_cache = c0
-        else:
-            a, (k, v) = A.gqa_full(cfg, p["attn"], h, positions,
-                                   causal=causal, window=window)
-            if mode == "prefill":
-                T = h.shape[1]
-                c0 = A.init_gqa_cache(cfg, B, _cache_len(cfg, kind, max_len),
-                                      window=window)
-                new_attn_cache = _fill_gqa_cache(cfg, c0, k, v, kpos_of(T),
-                                                 window=window)
+            if is_mla:
+                a, (ckv, kr) = A.mla_full(cfg, p["attn"], h, positions, causal=causal)
+                if mode == "prefill":
+                    T = h.shape[1]
+                    c0 = A.init_mla_cache(cfg, B, max_len)
+                    kp = kpos_of(T)
+                    c0 = dict(c0,
+                              ckv=jax.lax.dynamic_update_slice_in_dim(
+                                  c0["ckv"], ckv.astype(c0["ckv"].dtype), 0, 1),
+                              kr=jax.lax.dynamic_update_slice_in_dim(
+                                  c0["kr"], kr.astype(c0["kr"].dtype), 0, 1),
+                              kpos=jax.lax.dynamic_update_slice_in_dim(c0["kpos"], kp, 0, 1))
+                    new_attn_cache = c0
+            else:
+                a, (k, v) = A.gqa_full(cfg, p["attn"], h, positions,
+                                       causal=causal, window=window)
+                if mode == "prefill":
+                    T = h.shape[1]
+                    c0 = A.init_gqa_cache(cfg, B, _cache_len(cfg, kind, max_len),
+                                          window=window)
+                    new_attn_cache = _fill_gqa_cache(cfg, c0, k, v, kpos_of(T),
+                                                     window=window)
 
     # hybrid: parallel SSM branch, outputs fused via per-branch norms
     if kind.startswith("hyb"):
@@ -339,12 +340,13 @@ def block_apply(cfg: ModelConfig, kind: str, p, x, positions, mode: str,
         x = x + xa
 
     # FFN
-    h2 = apply_norm(cfg, p["ln2"], x)
-    if kind == "moe":
-        y, moe_aux = M.apply_moe(cfg, p["moe"], h2)
-        aux.update(moe_aux)
-    else:
-        y = apply_mlp(cfg, p["mlp"], h2)
+    with jax.named_scope("sprout.mlp"):
+        h2 = apply_norm(cfg, p["ln2"], x)
+        if kind == "moe":
+            y, moe_aux = M.apply_moe(cfg, p["moe"], h2)
+            aux.update(moe_aux)
+        else:
+            y = apply_mlp(cfg, p["mlp"], h2)
     y = jax.ad_checkpoint.checkpoint_name(y, "ffn_out")
     x = x + y
     return x, new_cache, aux
@@ -357,14 +359,16 @@ def block_apply_chunk(cfg: ModelConfig, kind: str, p, x, positions, valid,
     everything written so far; the FFN is position-wise as usual. Only
     dense / moe kinds reach here (``chunked_prefill_supported``)."""
     h = apply_norm(cfg, p["ln1"], x)
-    a, cache = A.gqa_chunk_append(cfg, p["attn"], h, positions, valid, lane,
-                                  cache, block_table=block_table)
+    with jax.named_scope("sprout.attention"):
+        a, cache = A.gqa_chunk_append(cfg, p["attn"], h, positions, valid,
+                                      lane, cache, block_table=block_table)
     x = x + a
-    h2 = apply_norm(cfg, p["ln2"], x)
-    if kind == "moe":
-        y, _ = M.apply_moe(cfg, p["moe"], h2)
-    else:
-        y = apply_mlp(cfg, p["mlp"], h2)
+    with jax.named_scope("sprout.mlp"):
+        h2 = apply_norm(cfg, p["ln2"], x)
+        if kind == "moe":
+            y, _ = M.apply_moe(cfg, p["moe"], h2)
+        else:
+            y = apply_mlp(cfg, p["mlp"], h2)
     return x + y, cache
 
 
@@ -398,9 +402,10 @@ def prefill_chunk_step(cfg: ModelConfig, params, tokens, pos0, clen, lane,
         x, c2 = jax.lax.scan(body, x, (params["segs"][i], cache[i]))
         new_caches.append(c2)
 
-    x = apply_norm(cfg, params["norm"], x)
-    last = jnp.clip(clen - 1, 0, C - 1)
-    logits = unembed(cfg, params["embed"], x[:, last][:, None])[0, 0]
+    with jax.named_scope("sprout.lm_head"):
+        x = apply_norm(cfg, params["norm"], x)
+        last = jnp.clip(clen - 1, 0, C - 1)
+        logits = unembed(cfg, params["embed"], x[:, last][:, None])[0, 0]
     return logits, new_caches
 
 
@@ -539,10 +544,11 @@ def forward(cfg: ModelConfig, params, tokens, *, mode: str = "train",
         auxs_list.append(auxs)
         x = hint(x, "act_resid")
 
-    x = apply_norm(cfg, params["norm"], x)
-    hidden = x
-    logits = unembed(cfg, params["embed"], x)
-    logits = hint(logits, "act_logits")
+    with jax.named_scope("sprout.lm_head"):
+        x = apply_norm(cfg, params["norm"], x)
+        hidden = x
+        logits = unembed(cfg, params["embed"], x)
+        logits = hint(logits, "act_logits")
     aux = _mean_aux(auxs_list)
     if return_hidden:
         aux["hidden"] = hidden

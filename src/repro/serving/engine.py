@@ -91,6 +91,7 @@ import numpy as np
 
 from repro.models import model as MD
 from repro.models.common import ModelConfig
+from repro.serving import spans
 from repro.serving.kv_cache import PageAllocator
 from repro.serving.sampler import (SamplingParams, greedy_sample,
                                    sample_logits_batched,
@@ -100,6 +101,15 @@ from repro.serving.tokenizer import ByteTokenizer
 
 def _next_pow2(n: int) -> int:
     return 1 << max(n - 1, 0).bit_length()
+
+
+def _mark_first_token(st: "RequestState") -> None:
+    """TTFT split at admission: queue wait (scheduler submit to slot) and
+    prefill (slot to first token), host microseconds; they sum to
+    ``FinishedRequest.ttft_s``."""
+    spans.mark(spans.FIRST_TOKEN, rid=st.rid,
+               wait_us=1e6 * (st.t_admit - st.t_submit),
+               prefill_us=1e6 * (st.t_first_token - st.t_admit))
 
 
 @dataclasses.dataclass
@@ -118,6 +128,9 @@ class RequestState:
     # latency — and therefore deadline attainment — is measured end to end
     # including any redo, not per-engine
     t_submit: float = 0.0
+    # when the request last took a slot (monotonic): the first-token mark
+    # splits TTFT at it into queue wait and prefill
+    t_admit: float = 0.0
     t_first_token: float = 0.0
     t_done: float = 0.0
     # SLO identity: the tenant service class, dispatch priority, and the
@@ -537,10 +550,14 @@ class InferenceEngine:
             plen = min(self._bucket(len(ids)), self.max_len)
             groups.setdefault(plen, []).append((slot, st, ids))
         for plen, grp in groups.items():
-            self._prefill_group(plen, grp)
+            with spans.span(spans.ENGINE_PREFILL, bs=len(grp), plen=plen):
+                self._prefill_group(plen, grp)
 
     def _prefill_group(self, plen: int,
                        grp: List[Tuple[int, RequestState, List[int]]]) -> None:
+        t_admit = time.monotonic()
+        for _, st, _ in grp:
+            st.t_admit = t_admit
         # pad the batch to a power of two so prefill/insert trace at most
         # log2(n_slots)+1 shapes; pad rows scatter to slot index n_slots,
         # which is out of bounds and therefore dropped by the insert
@@ -602,6 +619,7 @@ class InferenceEngine:
             st.slot = slot
             st.generated = [first]
             st.t_first_token = t_first
+            _mark_first_token(st)
             self.slots[slot] = st
             self.positions[slot] = st.prompt_len
             self.last_token[slot] = first
@@ -653,6 +671,7 @@ class InferenceEngine:
             self._committed += need
             st.reserved_pages = need
         self.queue.pop(0)
+        st.t_admit = time.monotonic()
         st.prompt_len = len(ids)
         st.slot = slot
         st.generated = []
@@ -737,7 +756,12 @@ class InferenceEngine:
         warm = name in self.entry_points
         if not warm:
             cfg, eos_id, max_len = self.cfg, self.eos_id, self.max_len
-            sample_fn = self._SAMPLE_FNS[mode]
+            draw = self._SAMPLE_FNS[mode]
+
+            def sample_fn(*args, **kwargs):
+                with jax.named_scope(spans.SAMPLER):
+                    return draw(*args, **kwargs)
+
             paged, paged_impl = self.paged, self.paged_impl
             bucketed = bs < self.n_slots
             has_chunk = chunk_c > 0
@@ -780,9 +804,10 @@ class InferenceEngine:
                     if has_chunk:
                         ctoks, cpos0, clen, cfinal = xs
                         lane = st["chunk_lane"]
-                        logits, part = MD.prefill_chunk_step(
-                            cfg, params, ctoks, cpos0, clen, lane, part,
-                            block_table=block_table if paged else None)
+                        with jax.named_scope(spans.PREFILL_CHUNK):
+                            logits, part = MD.prefill_chunk_step(
+                                cfg, params, ctoks, cpos0, clen, lane, part,
+                                block_table=block_table if paged else None)
                         # chunk-lane health: any dispatched chunk (clen > 0)
                         # with non-finite logits marks the lane bad — its
                         # half-written KV is garbage even before it samples
@@ -847,235 +872,256 @@ class InferenceEngine:
         every live lane and streaming at most one prompt chunk — in the
         compiled entry point bucketed to live occupancy. Returns the
         number of tokens emitted (0 if idle)."""
-        self._try_prefill()
-        self.peak_concurrent = max(
-            self.peak_concurrent, sum(s is not None for s in self.slots))
-        if self.paged:
-            self.peak_pages_in_use = max(self.peak_pages_in_use,
-                                         self.pages.pages_in_use())
-        task = self._task
-        if not self.live.any() and task is None:
-            return 0
-        # ----- bucket selection: live lanes plus the chunk-task lane ----
-        lanes = set(int(i) for i in np.nonzero(self.live)[0])
-        if task is not None:
-            lanes.add(task.slot)
-        rows = np.sort(np.fromiter(lanes, np.int64))
-        bs = (min(self.n_slots, _next_pow2(len(rows)))
-              if self._bucketing else self.n_slots)
-        if bs == self.n_slots:
-            rows_full = np.arange(self.n_slots, dtype=np.int64)
-        else:
-            rows_full = np.full(bs, self.n_slots, np.int64)
-            rows_full[: len(rows)] = rows
-        # ----- block length + per-step chunk arrays ---------------------
-        k = self._pick_k() if self.live.any() else self.decode_block
-        chunk_c = 0
-        chunk_xs = None
-        finishing = False
-        nxt_p = 0
-        if task is not None:
-            chunk_c = task.chunk
-            rem = -(-(task.plen - task.next) // chunk_c)
-            # shrink the block toward the chunks actually left, so a short
-            # tail does not pay (and a fresh variant does not compile for)
-            # a full block of dead chunk steps
-            k = max(1, min(k, _next_pow2(rem)))
-            ctoks = np.zeros((k, chunk_c), np.int32)
-            cpos0 = np.zeros(k, np.int32)
-            clen = np.zeros(k, np.int32)
-            cfin = np.zeros(k, bool)
-            nxt_p = task.next
-            for s in range(k):
-                if nxt_p < task.plen:
-                    n = min(chunk_c, task.plen - nxt_p)
-                    ctoks[s, :n] = task.ids[nxt_p:nxt_p + n]
-                    cpos0[s] = nxt_p
-                    clen[s] = n
-                    cfin[s] = nxt_p + n == task.plen
-                    nxt_p += n
-                    self.chunk_steps += 1
-            finishing = nxt_p == task.plen
-            chunk_xs = (jnp.asarray(ctoks), jnp.asarray(cpos0),
-                        jnp.asarray(clen), jnp.asarray(cfin))
-        # greedy rows (temp<=0) draw via argmax and ignore top-k/top-p, so
-        # only the *sampled* rows' params decide how much sampler to
-        # compile; the chunk lane counts — its first token draws in-scan
-        consider = np.zeros(self.n_slots, bool)
-        consider[rows] = True
-        drawn = consider & (self.temp > 0)
-        if not drawn.any():
-            mode = "greedy"
-        elif np.any((self.top_k[drawn] > 0) | (self.top_p[drawn] < 1.0)):
-            mode = "full"
-        else:
-            mode = "temp"
-        block_table = None
-        if self.paged:
-            if self._prefix_ok:
-                # copy-on-write (DESIGN.md §13): a write landing in a
-                # shared or adopted page remaps the lane onto a fresh page
-                # and duplicates the contents device-side BEFORE this
-                # block's appends. In practice only the fully-cached
-                # page-aligned prompt case fires (its 1-token recompute
-                # writes into the last adopted page); the admission
-                # reservation budgeted that page, so _alloc cannot fail.
-                # The live-lane sweep is a belt-and-braces invariant —
-                # decode appends always land past the shared span
-                targets = [(int(i), int(self.positions[i]))
-                           for i in np.nonzero(self.live)[0]]
-                if task is not None:
-                    targets.append((task.slot, task.next))
-                for tslot, tpos in targets:
-                    cw = self.pages.prepare_append(tslot, tpos)
-                    if cw is not None:
-                        self.cache = self._copy_page_jit(
-                            self.cache, jnp.asarray(cw[0], jnp.int32),
-                            jnp.asarray(cw[1], jnp.int32))
-            # grow each live slot's page map to cover this block's appends
-            # (bounded by the slot's own cap, so growth never exceeds the
-            # admission-time reservation and can never throw here)
-            for i in np.nonzero(self.live)[0]:
-                st = self.slots[i]
-                self.pages.ensure_capacity(
-                    int(i), min(int(self.positions[i]) + k,
-                                self._slot_cap(st.prompt_len,
-                                               st.max_new_tokens)))
-            if task is not None:
-                # per-chunk page growth: map only what this block writes
-                # (prompt chunks, plus up to k decode appends after an
-                # in-block transition) instead of the whole prompt at once
-                st = self.slots[task.slot]
-                cap = self._slot_cap(st.prompt_len, st.max_new_tokens)
-                tgt = min(nxt_p + (k if finishing else 0), cap)
-                self.pages_grown_chunked += self.pages.ensure_capacity(
-                    task.slot, tgt)
-            self.peak_pages_in_use = max(self.peak_pages_in_use,
-                                         self.pages.pages_in_use())
-            bt = np.full((bs, self.pages.max_pages), -1, np.int32)
-            real = rows_full < self.n_slots
-            bt[real] = self.pages.block_table[rows_full[real]]
-            block_table = jnp.asarray(bt)
-        # ----- bucketed device state (host mirrors, gathered) -----------
-        self.key, bk = jax.random.split(self.key)
-        real = rows_full < self.n_slots
-
-        def gath(a, fill, dtype):
-            out = np.full(bs, fill, dtype)
-            out[real] = a[rows_full[real]]
-            return jnp.asarray(out)
-
-        state = {
-            "last": gath(self.last_token, 0, np.int32),
-            "pos": gath(self.positions, 0, np.int32),
-            "live": gath(self.live, False, bool),
-            "gc": gath(self.gen_count, 0, np.int32),
-            "max_new": gath(self.max_new, 1, np.int32),
-            "temp": gath(self.temp, 0.0, np.float32),
-            "topk": gath(self.top_k, 0, np.int32),
-            "topp": gath(self.top_p, 1.0, np.float32),
-            "key": bk,
-            "rows": jnp.asarray(rows_full, jnp.int32),
-            # per-lane finiteness verdict, accumulated across the block's
-            # scan steps (sticky-False once a live lane's logits go bad)
-            "ok": jnp.ones(bs, bool),
-        }
-        if task is not None:
-            lane_pos = int(np.nonzero(rows_full == task.slot)[0][0])
-            state["chunk_lane"] = jnp.asarray(lane_pos, jnp.int32)
-        fn, warm = self._fused_for(k, mode, bs, chunk_c)
-        t_dec = time.monotonic()
-        # a tp engine traces under its mesh, so the paged kernel (which
-        # GSPMD cannot partition) finds the mesh to shard_map over
-        with (jax.set_mesh(self.shard_spec.mesh) if self.shard_spec
-              else contextlib.nullcontext()):
-            self.cache, toks, valid, live_dev, ok_dev = fn(
-                self.params, self.cache, block_table, state, chunk_xs)
-        # sproutlint: allow(SPL001) — the single host<->device sync for
-        # this block of <= k*bs tokens; the per-lane finiteness verdict
-        # rides the SAME fetch (no extra sync for fault detection); budget
-        # in analysis.config.ALLOWLIST
-        toks, valid, live_final, ok_final = jax.device_get(
-            (toks, valid, live_dev, ok_dev))
-        # decode-only wall time for this dispatch; 0.0 when this variant
-        # just compiled, so the straggler detector never samples a compile
-        self.last_decode_s = (time.monotonic() - t_dec) if warm else 0.0
-        self.decode_syncs += 1
-        self.steps += k
-        finish_order: List[Tuple[int, int]] = []
-        n_decoded = 0
-        # partition each step's share of the block wall time across the
-        # slots live at that step, so per-request decode_s sums to the
-        # device's decode wall time (compile dispatches report 0.0);
-        # dead tail steps (block overshoot past the last finish) have no
-        # live slot, so their time is spread over the block's decoding
-        # slots pro rata — nothing goes unattributed
-        dt_step = self.last_decode_s / k
-        live_steps = valid.sum(axis=1)                       # (k,)
-        share = dt_step / np.maximum(live_steps, 1)
-        dead_s = dt_step * int((live_steps == 0).sum())
-        total_valid = max(int(valid.sum()), 1)
-        for b, i in enumerate(int(x) for x in rows_full):
-            if i >= self.n_slots:
-                continue
-            st = self.slots[i]
-            if st is None:
-                continue
-            col = valid[:, b]
-            news = [int(t) for t in toks[col, b]]
-            st.decode_s += float(share[col].sum()) \
-                + dead_s * len(news) / total_valid
-            if not ok_final[b]:
-                # non-finite logits: every token this lane emitted in the
-                # block is suspect. Record them on the state (the wasted-
-                # work ledger charges discarded tokens) and quarantine —
-                # no finish, no mirror advance; the requeue path resets
-                # generation from the verbatim prompt.
-                st.generated.extend(news)
-                self._quarantine(i, "decode.nonfinite")
-                continue
-            st.generated.extend(news)
-            n_decoded += len(news)
-            self.decode_tokens += len(news)
-            self.gen_count[i] += len(news)
-            self.positions[i] += len(news)
-            if self.paged:    # live tokens in pages == appended positions
-                self.pages.lengths[i] = self.positions[i]
-            if news:
-                self.last_token[i] = news[-1]
-            self.live[i] = bool(live_final[b])
-            # a dead lane finishes only if it emitted this block: the
-            # chunk-task lane sits occupied-but-dead (col all False) until
-            # its final chunk flips it live in-scan
-            if not live_final[b] and news:
-                finish_order.append((int(np.nonzero(col)[0][-1]), i))
-        # a quarantine above may have torn the chunk task down with its
-        # lane (self._task reset, pages released) — only advance the task
-        # if it is still the one we dispatched
-        if task is not None and self._task is task:
-            i = task.slot
-            task.next = nxt_p
-            if finishing:
-                # the first token emitted from inside the scan: it lands at
-                # the pre-staged position, so it is not a position advance
-                self.positions[i] -= 1
-                st = self.slots[i]
-                if st is not None and st.t_first_token == 0.0:
-                    st.t_first_token = time.monotonic()
-                if st is not None and self._prefix_ok:
-                    # the whole prompt's KV is now written: index its full
-                    # pages for future prefix hits (adopted pages are
-                    # already indexed and skip; first registration wins)
-                    self.pages.register_prefix(i, task.ids)
-                self._task = None
+        with spans.span(spans.ENGINE_STEP, lanes=int(self.live.sum())):
+            with spans.span(spans.ENGINE_ADMIT, queued=len(self.queue)):
+                self._try_prefill()
+            self.peak_concurrent = max(
+                self.peak_concurrent, sum(s is not None for s in self.slots))
             if self.paged:
-                self.pages.lengths[i] = (int(self.positions[i]) if finishing
-                                         else task.next)
-        # finish in (step-within-block, slot) order so completion order is
-        # identical to single-step execution
-        for _, i in sorted(finish_order):
-            self._finish(i)
-        return n_decoded
+                self.peak_pages_in_use = max(self.peak_pages_in_use,
+                                             self.pages.pages_in_use())
+            task = self._task
+            if not self.live.any() and task is None:
+                return 0
+            with spans.span(spans.ENGINE_PREPARE):
+                # ----- bucket selection: live lanes plus the chunk-task lane -
+                lanes = set(int(i) for i in np.nonzero(self.live)[0])
+                if task is not None:
+                    lanes.add(task.slot)
+                rows = np.sort(np.fromiter(lanes, np.int64))
+                bs = (min(self.n_slots, _next_pow2(len(rows)))
+                      if self._bucketing else self.n_slots)
+                if bs == self.n_slots:
+                    rows_full = np.arange(self.n_slots, dtype=np.int64)
+                else:
+                    rows_full = np.full(bs, self.n_slots, np.int64)
+                    rows_full[: len(rows)] = rows
+                # ----- block length + per-step chunk arrays ------------------
+                k = self._pick_k() if self.live.any() else self.decode_block
+                chunk_c = 0
+                chunk_xs = None
+                finishing = False
+                nxt_p = 0
+                if task is not None:
+                    chunk_c = task.chunk
+                    rem = -(-(task.plen - task.next) // chunk_c)
+                    # shrink the block toward the chunks actually left, so a
+                    # short tail does not pay (and a fresh variant does not
+                    # compile for) a full block of dead chunk steps
+                    k = max(1, min(k, _next_pow2(rem)))
+                    ctoks = np.zeros((k, chunk_c), np.int32)
+                    cpos0 = np.zeros(k, np.int32)
+                    clen = np.zeros(k, np.int32)
+                    cfin = np.zeros(k, bool)
+                    nxt_p = task.next
+                    for s in range(k):
+                        if nxt_p < task.plen:
+                            n = min(chunk_c, task.plen - nxt_p)
+                            ctoks[s, :n] = task.ids[nxt_p:nxt_p + n]
+                            cpos0[s] = nxt_p
+                            clen[s] = n
+                            cfin[s] = nxt_p + n == task.plen
+                            nxt_p += n
+                            self.chunk_steps += 1
+                    finishing = nxt_p == task.plen
+                    chunk_xs = (jnp.asarray(ctoks), jnp.asarray(cpos0),
+                                jnp.asarray(clen), jnp.asarray(cfin))
+                # greedy rows (temp<=0) draw via argmax and ignore top-k/top-p,
+                # so only the *sampled* rows' params decide how much sampler to
+                # compile; the chunk lane counts — its first token draws
+                # in-scan
+                consider = np.zeros(self.n_slots, bool)
+                consider[rows] = True
+                drawn = consider & (self.temp > 0)
+                if not drawn.any():
+                    mode = "greedy"
+                elif np.any((self.top_k[drawn] > 0)
+                            | (self.top_p[drawn] < 1.0)):
+                    mode = "full"
+                else:
+                    mode = "temp"
+                block_table = None
+                if self.paged:
+                    if self._prefix_ok:
+                        # copy-on-write (DESIGN.md §13): a write landing in a
+                        # shared or adopted page remaps the lane onto a fresh
+                        # page and duplicates the contents device-side BEFORE
+                        # this block's appends. In practice only the
+                        # fully-cached page-aligned prompt case fires (its
+                        # 1-token recompute writes into the last adopted page);
+                        # the admission reservation budgeted that page, so
+                        # _alloc cannot fail. The live-lane sweep is a
+                        # belt-and-braces invariant — decode appends always
+                        # land past the shared span
+                        targets = [(int(i), int(self.positions[i]))
+                                   for i in np.nonzero(self.live)[0]]
+                        if task is not None:
+                            targets.append((task.slot, task.next))
+                        for tslot, tpos in targets:
+                            cw = self.pages.prepare_append(tslot, tpos)
+                            if cw is not None:
+                                self.cache = self._copy_page_jit(
+                                    self.cache, jnp.asarray(cw[0], jnp.int32),
+                                    jnp.asarray(cw[1], jnp.int32))
+                    # grow each live slot's page map to cover this block's
+                    # appends (bounded by the slot's own cap, so growth never
+                    # exceeds the admission-time reservation and can never
+                    # throw here)
+                    for i in np.nonzero(self.live)[0]:
+                        st = self.slots[i]
+                        self.pages.ensure_capacity(
+                            int(i), min(int(self.positions[i]) + k,
+                                        self._slot_cap(st.prompt_len,
+                                                       st.max_new_tokens)))
+                    if task is not None:
+                        # per-chunk page growth: map only what this block
+                        # writes (prompt chunks, plus up to k decode appends
+                        # after an in-block transition) instead of the whole
+                        # prompt at once
+                        st = self.slots[task.slot]
+                        cap = self._slot_cap(st.prompt_len, st.max_new_tokens)
+                        tgt = min(nxt_p + (k if finishing else 0), cap)
+                        self.pages_grown_chunked += self.pages.ensure_capacity(
+                            task.slot, tgt)
+                    self.peak_pages_in_use = max(self.peak_pages_in_use,
+                                                 self.pages.pages_in_use())
+                    bt = np.full((bs, self.pages.max_pages), -1, np.int32)
+                    real = rows_full < self.n_slots
+                    bt[real] = self.pages.block_table[rows_full[real]]
+                    block_table = jnp.asarray(bt)
+                # ----- bucketed device state (host mirrors, gathered) --------
+                self.key, bk = jax.random.split(self.key)
+                real = rows_full < self.n_slots
+
+                def gath(a, fill, dtype):
+                    out = np.full(bs, fill, dtype)
+                    out[real] = a[rows_full[real]]
+                    return jnp.asarray(out)
+
+                state = {
+                    "last": gath(self.last_token, 0, np.int32),
+                    "pos": gath(self.positions, 0, np.int32),
+                    "live": gath(self.live, False, bool),
+                    "gc": gath(self.gen_count, 0, np.int32),
+                    "max_new": gath(self.max_new, 1, np.int32),
+                    "temp": gath(self.temp, 0.0, np.float32),
+                    "topk": gath(self.top_k, 0, np.int32),
+                    "topp": gath(self.top_p, 1.0, np.float32),
+                    "key": bk,
+                    "rows": jnp.asarray(rows_full, jnp.int32),
+                    # per-lane finiteness verdict, accumulated across the
+                    # block's scan steps (sticky-False once a live lane's
+                    # logits go bad)
+                    "ok": jnp.ones(bs, bool),
+                }
+                if task is not None:
+                    lane_pos = int(np.nonzero(rows_full == task.slot)[0][0])
+                    state["chunk_lane"] = jnp.asarray(lane_pos, jnp.int32)
+                fn, warm = self._fused_for(k, mode, bs, chunk_c)
+            t_dec = time.monotonic()
+            with spans.span(spans.ENGINE_DISPATCH, bs=bs, k=k, mode=mode,
+                            chunk=chunk_c, cold=int(not warm)):
+                # a tp engine traces under its mesh, so the paged kernel (which
+                # GSPMD cannot partition) finds the mesh to shard_map over
+                with (jax.set_mesh(self.shard_spec.mesh) if self.shard_spec
+                      else contextlib.nullcontext()):
+                    self.cache, toks, valid, live_dev, ok_dev = fn(
+                        self.params, self.cache, block_table, state, chunk_xs)
+            with spans.span(spans.ENGINE_FETCH):
+                # sproutlint: allow(SPL001) — the single host<->device sync
+                # for this block of <= k*bs tokens; the per-lane finiteness
+                # verdict rides the SAME fetch (no extra sync for fault
+                # detection); budget in analysis.config.ALLOWLIST
+                toks, valid, live_final, ok_final = jax.device_get(
+                    (toks, valid, live_dev, ok_dev))
+            with spans.span(spans.ENGINE_EMIT) as emit:
+                # decode-only wall time for this dispatch; 0.0 when this
+                # variant just compiled, so the straggler detector never
+                # samples a compile
+                self.last_decode_s = ((time.monotonic() - t_dec) if warm
+                                      else 0.0)
+                self.decode_syncs += 1
+                self.steps += k
+                finish_order: List[Tuple[int, int]] = []
+                n_decoded = 0
+                # partition each step's share of the block wall time across the
+                # slots live at that step, so per-request decode_s sums to the
+                # device's decode wall time (compile dispatches report 0.0);
+                # dead tail steps (block overshoot past the last finish) have
+                # no live slot, so their time is spread over the block's
+                # decoding slots pro rata — nothing goes unattributed
+                dt_step = self.last_decode_s / k
+                live_steps = valid.sum(axis=1)                       # (k,)
+                share = dt_step / np.maximum(live_steps, 1)
+                dead_s = dt_step * int((live_steps == 0).sum())
+                total_valid = max(int(valid.sum()), 1)
+                for b, i in enumerate(int(x) for x in rows_full):
+                    if i >= self.n_slots:
+                        continue
+                    st = self.slots[i]
+                    if st is None:
+                        continue
+                    col = valid[:, b]
+                    news = [int(t) for t in toks[col, b]]
+                    st.decode_s += float(share[col].sum()) \
+                        + dead_s * len(news) / total_valid
+                    if not ok_final[b]:
+                        # non-finite logits: every token this lane emitted in
+                        # the block is suspect. Record them on the state (the
+                        # wasted-work ledger charges discarded tokens) and
+                        # quarantine — no finish, no mirror advance; the
+                        # requeue path resets generation from the verbatim
+                        # prompt.
+                        st.generated.extend(news)
+                        self._quarantine(i, "decode.nonfinite")
+                        continue
+                    st.generated.extend(news)
+                    n_decoded += len(news)
+                    self.decode_tokens += len(news)
+                    self.gen_count[i] += len(news)
+                    self.positions[i] += len(news)
+                    if self.paged:    # live tokens in pages == positions
+                        self.pages.lengths[i] = self.positions[i]
+                    if news:
+                        self.last_token[i] = news[-1]
+                    self.live[i] = bool(live_final[b])
+                    # a dead lane finishes only if it emitted this block: the
+                    # chunk-task lane sits occupied-but-dead (col all False)
+                    # until its final chunk flips it live in-scan
+                    if not live_final[b] and news:
+                        finish_order.append((int(np.nonzero(col)[0][-1]), i))
+                # a quarantine above may have torn the chunk task down with its
+                # lane (self._task reset, pages released) — only advance the
+                # task if it is still the one we dispatched
+                if task is not None and self._task is task:
+                    i = task.slot
+                    task.next = nxt_p
+                    if finishing:
+                        # the first token emitted from inside the scan: it
+                        # lands at the pre-staged position, so it is not a
+                        # position advance
+                        self.positions[i] -= 1
+                        st = self.slots[i]
+                        if st is not None and st.t_first_token == 0.0:
+                            st.t_first_token = time.monotonic()
+                            _mark_first_token(st)
+                        if st is not None and self._prefix_ok:
+                            # the whole prompt's KV is now written: index its
+                            # full pages for future prefix hits (adopted pages
+                            # are already indexed and skip; first registration
+                            # wins)
+                            self.pages.register_prefix(i, task.ids)
+                        self._task = None
+                    if self.paged:
+                        self.pages.lengths[i] = (int(self.positions[i])
+                                                 if finishing else task.next)
+                # finish in (step-within-block, slot) order so completion order
+                # is identical to single-step execution
+                for _, i in sorted(finish_order):
+                    self._finish(i)
+                emit.set_metadata(tokens=n_decoded,
+                                  finished=len(finish_order))
+            return n_decoded
 
     # ------------------------------------------------------------------
     def run_to_completion(self, max_steps: int = 100000) -> List[FinishedRequest]:

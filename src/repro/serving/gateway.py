@@ -83,6 +83,7 @@ from repro.core.lp import TenantSpec, forecast_weighted_intensity, \
     solve_tenant_lps
 from repro.core.policies import LevelProfiles, Policy
 from repro.core.workload import N_LEVELS, Request
+from repro.serving import spans
 from repro.serving.engine import FinishedRequest
 from repro.serving.faults import FaultInjector, no_faults
 from repro.serving.scheduler import CarbonAwareScheduler, ServeRequest
@@ -1048,46 +1049,47 @@ class SproutGateway:
         ``pool.x`` aggregate installed by re-planning remains in use for
         migration's energy expectation and for requests fed straight
         into a pool's scheduler, bypassing this router."""
-        if self.tenants is not None:
-            spec = self._tenant_of(req)
-            req.tenant = spec.name
-            req.priority = spec.priority
-            if math.isinf(req.deadline_s) and math.isinf(req.deadline_at):
-                req.deadline_s = spec.deadline_for(req.max_new_tokens)
-        if self.brownout and req.priority >= 2:
-            # brownout sheds the BATCH tier first: deferrable work is
-            # turned away at the door (rid 0 = not admitted) so the
-            # faulting fleet's remaining capacity serves latency- and
-            # quality-bound tenants; premium/standard always admit
-            self.stats.shed += 1
-            return 0, "shed"
-        if req.task:
-            self._task_counts[req.task] = \
-                self._task_counts.get(req.task, 0.0) + 1.0
-        alive = [p for p in self.pools
-                 if any(e is not None for e in p.scheduler.engines)]
-        open_ = [p for p in alive if p.key not in self.draining]
-        candidates = open_ or alive or self.pools
-        by_carbon = sorted(candidates, key=self.plan_intensity)
-        deadline = req.deadline_s if math.isinf(req.deadline_at) else \
-            req.deadline_at - time.monotonic()
-        if not math.isinf(deadline):
-            fits = [p for p in by_carbon
-                    if self.predicted_completion_s(
-                        p, max_new=req.max_new_tokens,
-                        tenant=req.tenant) <= deadline]
-            pool = (next((p for p in fits if p.load() < self.load_cap),
-                         fits[0]) if fits
-                    else min(candidates, key=lambda p:
-                             self.predicted_completion_s(
-                                 p, max_new=req.max_new_tokens,
-                                 tenant=req.tenant)))
-        else:
-            pool = next((p for p in by_carbon if p.load() < self.load_cap),
-                        min(candidates, key=lambda p: p.load()))
-        rid = pool.scheduler.submit(req)
-        pool.routed += 1
-        return rid, pool.key
+        with spans.span(spans.GATEWAY_SUBMIT):
+            if self.tenants is not None:
+                spec = self._tenant_of(req)
+                req.tenant = spec.name
+                req.priority = spec.priority
+                if math.isinf(req.deadline_s) and math.isinf(req.deadline_at):
+                    req.deadline_s = spec.deadline_for(req.max_new_tokens)
+            if self.brownout and req.priority >= 2:
+                # brownout sheds the BATCH tier first: deferrable work is
+                # turned away at the door (rid 0 = not admitted) so the
+                # faulting fleet's remaining capacity serves latency- and
+                # quality-bound tenants; premium/standard always admit
+                self.stats.shed += 1
+                return 0, "shed"
+            if req.task:
+                self._task_counts[req.task] = \
+                    self._task_counts.get(req.task, 0.0) + 1.0
+            alive = [p for p in self.pools
+                     if any(e is not None for e in p.scheduler.engines)]
+            open_ = [p for p in alive if p.key not in self.draining]
+            candidates = open_ or alive or self.pools
+            by_carbon = sorted(candidates, key=self.plan_intensity)
+            deadline = req.deadline_s if math.isinf(req.deadline_at) else \
+                req.deadline_at - time.monotonic()
+            if not math.isinf(deadline):
+                fits = [p for p in by_carbon
+                        if self.predicted_completion_s(
+                            p, max_new=req.max_new_tokens,
+                            tenant=req.tenant) <= deadline]
+                pool = (next((p for p in fits if p.load() < self.load_cap),
+                             fits[0]) if fits
+                        else min(candidates, key=lambda p:
+                                 self.predicted_completion_s(
+                                     p, max_new=req.max_new_tokens,
+                                     tenant=req.tenant)))
+            else:
+                pool = next((p for p in by_carbon if p.load() < self.load_cap),
+                            min(candidates, key=lambda p: p.load()))
+            rid = pool.scheduler.submit(req)
+            pool.routed += 1
+            return rid, pool.key
 
     def drain_pool(self, region: str, deadline: Optional[float] = None
                    ) -> int:
@@ -1120,20 +1122,23 @@ class SproutGateway:
         wasted-carbon ledger — a retried request's first attempt burned
         real energy that conservation accounting must not drop)."""
         tokens = 0
-        for pool in self.pools:
-            tokens += pool.scheduler.step()
-            ev = pool.scheduler.fault_events
-            if ev:
-                pool.scheduler.fault_events = []
-                for _reason, rst in ev:
-                    self.stats.faults += 1
-                    self._fault_score += 1.0
-                    self.account_wasted(pool, rst.prompt_len,
-                                        len(rst.generated))
-            if pool.scheduler.finished:
-                for fin in pool.scheduler.finished:
-                    self._account(pool, fin)
-                pool.scheduler.finished = []
+        with spans.span(spans.GATEWAY_STEP):
+            for pool in self.pools:
+                tokens += pool.scheduler.step()
+                ev = pool.scheduler.fault_events
+                if ev:
+                    pool.scheduler.fault_events = []
+                    for _reason, rst in ev:
+                        self.stats.faults += 1
+                        self._fault_score += 1.0
+                        self.account_wasted(pool, rst.prompt_len,
+                                            len(rst.generated))
+                if pool.scheduler.finished:
+                    with spans.span(spans.GATEWAY_ACCOUNT,
+                                    requests=len(pool.scheduler.finished)):
+                        for fin in pool.scheduler.finished:
+                            self._account(pool, fin)
+                    pool.scheduler.finished = []
         return tokens
 
     def drain(self, max_steps: int = 100000) -> None:

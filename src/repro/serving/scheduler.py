@@ -37,6 +37,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.core.directives import DirectiveSet
+from repro.serving import spans
 from repro.serving.engine import FinishedRequest, InferenceEngine, RequestState
 from repro.serving.faults import FaultInjector, no_faults
 from repro.serving.sampler import SamplingParams
@@ -176,10 +177,12 @@ class CarbonAwareScheduler:
         fn = self.level_fn
         return int(fn(req) if getattr(fn, "per_request", False) else fn())
 
-    def _dispatch(self) -> None:
+    def _dispatch(self) -> int:
+        """Move pending requests onto engines; returns how many moved."""
         live = [(i, e) for i, e in enumerate(self.engines) if e is not None]
         if not live:
-            return
+            return 0
+        moved = 0
         # priority order, stable within a class (sorted is stable): premium
         # dispatches — and therefore prefills — before batch every step
         self.pending.sort(key=lambda r: r.priority)
@@ -228,6 +231,7 @@ class CarbonAwareScheduler:
                                retries=req.retries,
                                last_fault=req.last_fault)
                     self._backoff.pop(req.rid, None)
+                    moved += 1
                     break
                 except ValueError as err:
                     # engine precondition (budget/empty prompt); a pool may
@@ -238,6 +242,7 @@ class CarbonAwareScheduler:
                 # instead of losing it or aborting the fleet step
                 self.rejected.append((req, str(last_err)))
         self.pending.extend(deferred)
+        return moved
 
     # ------------------------------------------------------------------
     def step(self) -> int:
@@ -245,7 +250,8 @@ class CarbonAwareScheduler:
         self.steps += 1
         self._consult_injector()
         self._tick_probation()
-        self._dispatch()
+        with spans.span(spans.SCHEDULER_DISPATCH) as dispatch:
+            dispatch.set_metadata(requests=self._dispatch())
         lanes = 0
         for i, eng in enumerate(self.engines):
             if eng is None:
