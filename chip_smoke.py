@@ -142,6 +142,7 @@ def phase_kernel(jax, clock) -> None:
     from repro.kernels import ops, ref
 
     B, H, KVH, D, P, max_pages = 8, 32, 8, 64, 64, MAX_LEN // PAGE
+    L, layer = 3, jnp.int32(1)           # a layer-stacked store, middle layer
     lengths = jnp.asarray([1, 127, 128, 129, 300, 512, 777, MAX_LEN],
                           jnp.int32)
     rng = np.random.default_rng(SEED)
@@ -149,26 +150,22 @@ def phase_kernel(jax, clock) -> None:
                      .reshape(B, max_pages), jnp.int32)
     ks = jax.random.split(jax.random.PRNGKey(SEED), 7)
     q = jax.random.normal(ks[0], (B, H, D), jnp.bfloat16)
+    kv, sc = (L, P, PAGE, KVH, D), (L, P, PAGE, KVH)
     cases = {
-        "bf16": (jax.random.normal(ks[1], (P, PAGE, KVH, D), jnp.bfloat16),
-                 jax.random.normal(ks[2], (P, PAGE, KVH, D), jnp.bfloat16),
-                 None, None),
-        "int8": (jax.random.randint(ks[3], (P, PAGE, KVH, D), -127, 128,
-                                    jnp.int8),
-                 jax.random.randint(ks[4], (P, PAGE, KVH, D), -127, 128,
-                                    jnp.int8),
-                 jax.random.uniform(ks[5], (P, PAGE, KVH), jnp.float32,
-                                    0.005, 0.02),
-                 jax.random.uniform(ks[6], (P, PAGE, KVH), jnp.float32,
-                                    0.005, 0.02)),
+        "bf16": (jax.random.normal(ks[1], kv, jnp.bfloat16),
+                 jax.random.normal(ks[2], kv, jnp.bfloat16), None, None),
+        "int8": (jax.random.randint(ks[3], kv, -127, 128, jnp.int8),
+                 jax.random.randint(ks[4], kv, -127, 128, jnp.int8),
+                 jax.random.uniform(ks[5], sc, jnp.float32, 0.005, 0.02),
+                 jax.random.uniform(ks[6], sc, jnp.float32, 0.005, 0.02)),
     }
     for name, (kp, vp, ksc, vsc) in cases.items():
         t0, c0 = time.monotonic(), clock.seconds
-        out = ops.paged_attention(q, kp, vp, bt, lengths, ksc, vsc,
+        out = ops.paged_attention(q, kp, vp, bt, lengths, layer, ksc, vsc,
                                   impl="pallas")
         with jax.default_matmul_precision("highest"):
             want = ref.paged_attention_ref(
-                q.astype(jnp.float32), kp, vp, bt, lengths, ksc, vsc)
+                q.astype(jnp.float32), kp, vp, bt, lengths, layer, ksc, vsc)
         err = float(jnp.max(jnp.abs(out.astype(jnp.float32) - want)))
         print(f"phase B: paged_attention {name} B={B} H={H} KVH={KVH} "
               f"D={D} page={PAGE}: max_abs_err={err:.3e} "

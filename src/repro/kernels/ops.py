@@ -62,9 +62,10 @@ def flash_attention(q, k, v, *, causal=True, window=0, block_q=128,
                   block_k=bk, interpret=auto_interpret())
 
 
-def paged_attention(q, k_pages, v_pages, block_table, lengths,
+def paged_attention(q, k_pages, v_pages, block_table, lengths, layer,
                     k_scale=None, v_scale=None, *, impl: str = "auto"):
-    """Decode attention over a block-table cache; see paged_attention.py.
+    """Decode attention over one layer of a layer-stacked block-table
+    cache; see paged_attention.py.
 
     ``impl``: "auto" (kernel on TPU, reference elsewhere), "pallas"
     (native lowering), "pallas_interpret" (kernel semantics on CPU — how
@@ -80,22 +81,23 @@ def paged_attention(q, k_pages, v_pages, block_table, lengths,
     impl = resolve_impl(impl)
     if impl == "xla":
         return ref.paged_attention_ref(q, k_pages, v_pages, block_table,
-                                       lengths, k_scale, v_scale)
+                                       lengths, layer, k_scale, v_scale)
     assert impl in ("pallas", "pallas_interpret"), impl
     kernel = functools.partial(_paged, interpret=impl == "pallas_interpret")
     scales = () if k_scale is None else (k_scale, v_scale)
     mesh = jax.sharding.get_abstract_mesh()
     tp = 1 if mesh.empty else mesh.shape.get("model", 1)
     if tp == 1:
-        return kernel(q, k_pages, v_pages, block_table, lengths, *scales)
-    heads = "model" if k_pages.shape[2] % tp == 0 else None
-    q_spec, kv_spec = P(None, heads, None), P(None, None, heads, None)
-    sc_spec = P(None, None, heads)
+        return kernel(q, k_pages, v_pages, block_table, lengths, layer,
+                      *scales)
+    heads = "model" if k_pages.shape[3] % tp == 0 else None
+    q_spec, kv_spec = P(None, heads, None), P(None, None, None, heads, None)
+    sc_spec = P(None, None, None, heads)
     return jax.shard_map(
         kernel, mesh=mesh, check_vma=False, out_specs=q_spec,
-        in_specs=(q_spec, kv_spec, kv_spec, P(), P())
+        in_specs=(q_spec, kv_spec, kv_spec, P(), P(), P())
         + (sc_spec,) * len(scales),
-    )(q, k_pages, v_pages, block_table, lengths, *scales)
+    )(q, k_pages, v_pages, block_table, lengths, layer, *scales)
 
 
 def fused_rmsnorm(x, scale, residual=None, *, eps: float = 1e-6):

@@ -31,27 +31,27 @@ def flash_attention_ref(q, k, v, *, causal: bool = True, window: int = 0):
     return out.reshape(B, H, Tq, D).astype(q.dtype)
 
 
-def paged_attention_ref(q, k_pages, v_pages, block_table, lengths,
+def paged_attention_ref(q, k_pages, v_pages, block_table, lengths, layer,
                         k_scale=None, v_scale=None):
-    """Decode attention over a paged cache.
+    """Decode attention over one layer of a stacked paged cache.
 
-    q: (B, H, D); k_pages/v_pages: (P, ps, KVH, D) (int8 when scales given,
-    scales (P, ps, KVH) f32); block_table: (B, max_pages) int32;
-    lengths: (B,) tokens per slot. Returns (B, H, D).
+    q: (B, H, D); k_pages/v_pages: (L, P, ps, KVH, D) (int8 when scales
+    given, scales (L, P, ps, KVH) f32); block_table: (B, max_pages) int32;
+    lengths: (B,) tokens per slot; layer: scalar int32. Returns (B, H, D).
     """
     B, H, D = q.shape
-    P, ps, KVH, _ = k_pages.shape
+    _, P, ps, KVH, _ = k_pages.shape
     max_pages = block_table.shape[1]
     S = max_pages * ps
     g = H // KVH
 
     def per_slot(qb, bt, L):
         pages = jnp.clip(bt, 0, P - 1)
-        kk = k_pages[pages].reshape(S, KVH, D).astype(jnp.float32)
-        vv = v_pages[pages].reshape(S, KVH, D).astype(jnp.float32)
+        kk = k_pages[layer, pages].reshape(S, KVH, D).astype(jnp.float32)
+        vv = v_pages[layer, pages].reshape(S, KVH, D).astype(jnp.float32)
         if k_scale is not None:
-            ks = k_scale[pages].reshape(S, KVH)
-            vs = v_scale[pages].reshape(S, KVH)
+            ks = k_scale[layer, pages].reshape(S, KVH)
+            vs = v_scale[layer, pages].reshape(S, KVH)
             kk = kk * ks[..., None]
             vv = vv * vs[..., None]
         qh = qb.reshape(KVH, g, D).astype(jnp.float32)

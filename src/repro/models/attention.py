@@ -10,13 +10,16 @@ Cache layout (GQA):  {"k": (B, S, n_kv, Dh), "v": ..., "kpos": (B, S) int32}
   which uniformly supports full caches, ring-buffer sliding windows, and
   continuous batching with ragged per-slot lengths.
 Cache layout (MLA):  {"ckv": (B, S, rank), "kr": (B, S, rope), "kpos": ...}
-Cache layout (paged GQA): {"k": (P, page, n_kv, Dh), "v": ...} — a block-table
-  page store shared by every slot; the per-slot page list and live lengths
-  arrive as separate decode-step inputs (``gqa_decode_paged``), and the
-  attention read runs through the Pallas paged kernel or its XLA reference
-  (kernels/ops.py dispatch).
+Cache layout (paged GQA): {"k": (L, P, page, n_kv, Dh), "v": ...} — one
+  block-table page store per segment, shared by every slot and stacked over
+  the segment's L layers. The paged functions take the whole stacked store
+  and a layer index: writes land at [layer, page, offset] and reads gather
+  (or DMA) that layer's pages, so a layer's store is never sliced out. The
+  per-slot page list and live lengths arrive as separate decode-step inputs
+  (``gqa_decode_paged``), and the attention read runs through the Pallas
+  paged kernel or its XLA reference (kernels/ops.py dispatch).
 Int8 KV (beyond-paper optimization): "k"/"v" stored int8 + "k_scale"/"v_scale"
-  (B, S, n_kv) float32 per-token-per-head scales (paged: (P, page, n_kv)).
+  (B, S, n_kv) float32 per-token-per-head scales (paged: (L, P, page, n_kv)).
 """
 from __future__ import annotations
 
@@ -260,7 +263,7 @@ def gqa_decode(cfg: ModelConfig, p, x, positions, cache, *, window: int = 0,
 # ======================================================================
 
 def gqa_chunk_append(cfg: ModelConfig, p, x, positions, valid, lane, cache,
-                     *, block_table=None):
+                     *, block_table=None, layer=None):
     """Append one prompt *chunk* for a single lane and attend causally over
     everything that lane has written so far (earlier chunks included).
 
@@ -277,11 +280,12 @@ def gqa_chunk_append(cfg: ModelConfig, p, x, positions, valid, lane, cache,
     request has not yet written carry kpos from a previous occupant only
     at positions > q_pos, which the causal mask excludes.
 
-    Paged cache (block_table given; leaves (P, ps, ...)): writes go
-    through the lane's block-table row (unmapped / invalid entries are
-    redirected to the out-of-bounds page P and dropped), reads gather the
-    lane's pages and mask by absolute index <= q_pos — positional
-    validity, exactly like decode.
+    Paged cache (block_table given; the segment's stacked store, leaves
+    (L, P, ps, ...), and this block's ``layer``): writes go through the
+    lane's block-table row to [layer, page, offset] (unmapped / invalid
+    entries are redirected to the out-of-bounds page P and dropped), reads
+    gather only the lane's pages of that layer and mask by absolute index
+    <= q_pos — positional validity, exactly like decode.
     """
     C = x.shape[1]
     h, dh = cfg.n_heads, cfg.head_dim
@@ -318,15 +322,15 @@ def gqa_chunk_append(cfg: ModelConfig, p, x, positions, valid, lane, cache,
         mask = _causal_window_mask(positions[None, :], cache["kpos"][lane][None],
                                    0, causal=True)
     else:
-        P, ps = cache["k"].shape[:2]
+        P, ps = cache["k"].shape[1:3]
         bt = block_table[lane]                       # (max_pages,)
         pidx = jnp.clip(positions // ps, 0, bt.shape[0] - 1)
         entry = bt[pidx]
         page = jnp.where(valid & (entry >= 0), entry, P)
         off = positions % ps
 
-        def wr(buf, val):          # buf (P, ps, ...), val (1, C, ...)
-            return buf.at[page, off].set(val[0].astype(buf.dtype))
+        def wr(buf, val):          # buf (L, P, ps, ...), val (1, C, ...)
+            return buf.at[layer, page, off].set(val[0].astype(buf.dtype))
 
         with jax.named_scope("sprout.kv_write"):
             if quantized:
@@ -339,11 +343,14 @@ def gqa_chunk_append(cfg: ModelConfig, p, x, positions, valid, lane, cache,
         # gather the lane's pages (clamped; stale/unmapped rows sit at
         # absolute indices > q_pos and are masked positionally)
         safe = jnp.clip(bt, 0, P - 1)
-        kp = cache["k"][safe].reshape(-1, *cache["k"].shape[2:])
-        vp = cache["v"][safe].reshape(-1, *cache["v"].shape[2:])
+
+        def lane_pages(buf):       # (max_pages * ps, ...) of this layer
+            return buf[layer, safe].reshape(-1, *buf.shape[3:])
+
+        kp, vp = lane_pages(cache["k"]), lane_pages(cache["v"])
         if quantized:
-            ksp = cache["k_scale"][safe].reshape(-1, *cache["k_scale"].shape[2:])
-            vsp = cache["v_scale"][safe].reshape(-1, *cache["v_scale"].shape[2:])
+            ksp = lane_pages(cache["k_scale"])
+            vsp = lane_pages(cache["v_scale"])
             k = dequantize_kv(kp, ksp, cfg.compute_dtype)[None]
             v = dequantize_kv(vp, vsp, cfg.compute_dtype)[None]
         else:
@@ -377,16 +384,18 @@ def init_paged_gqa_cache(cfg: ModelConfig, n_pages: int, page_size: int):
             "v": jnp.zeros((n_pages, page_size, kv, dh), cfg.kv_dtype)}
 
 
-def _paged_cache_write(cache, new_k, new_v, positions, block_table, live,
-                       quantized: bool):
-    """Append one token per row through the block table.
+def _paged_cache_write(store, layer, new_k, new_v, positions, block_table,
+                       live, quantized: bool):
+    """Append one token per row of ``layer`` through the block table: one
+    scatter per leaf of the stacked store at [layer, page, offset], which
+    leaves every other layer's pages untouched.
 
     Rows whose table entry is unmapped (-1) and rows whose lane is dead
     (``live`` False) are redirected to the out-of-bounds page id ``P`` —
     JAX drops out-of-bounds scatter updates, so a dead lane can never
     corrupt a page that was re-allocated to another slot mid-block.
     """
-    P, ps = cache["k"].shape[:2]
+    P, ps = store["k"].shape[1:3]
     entry = jnp.take_along_axis(block_table, (positions // ps)[:, None],
                                 axis=1)[:, 0]
     page = jnp.where(entry >= 0, entry, P)
@@ -394,30 +403,32 @@ def _paged_cache_write(cache, new_k, new_v, positions, block_table, live,
         page = jnp.where(live, page, P)
     off = positions % ps
 
-    def wr(buf, val):      # buf (P, ps, ...), val (B, ...) one token per row
-        return buf.at[page, off].set(val.astype(buf.dtype))
+    def wr(buf, val):  # buf (L, P, ps, ...), val (B, ...) one token per row
+        return buf.at[layer, page, off].set(val.astype(buf.dtype))
 
     if quantized:
         qk, sk = quantize_kv(new_k)
         qv, sv = quantize_kv(new_v)
-        return dict(cache,
-                    k=wr(cache["k"], qk[:, 0]), v=wr(cache["v"], qv[:, 0]),
-                    k_scale=wr(cache["k_scale"], sk[:, 0]),
-                    v_scale=wr(cache["v_scale"], sv[:, 0]))
-    return dict(cache, k=wr(cache["k"], new_k[:, 0]),
-                v=wr(cache["v"], new_v[:, 0]))
+        return dict(store,
+                    k=wr(store["k"], qk[:, 0]), v=wr(store["v"], qv[:, 0]),
+                    k_scale=wr(store["k_scale"], sk[:, 0]),
+                    v_scale=wr(store["v_scale"], sv[:, 0]))
+    return dict(store, k=wr(store["k"], new_k[:, 0]),
+                v=wr(store["v"], new_v[:, 0]))
 
 
-def gqa_decode_paged(cfg: ModelConfig, p, x, positions, cache, block_table,
-                     *, live=None, impl: str = "auto"):
-    """Paged decode step: append the new token's K/V through the block
-    table, then attend over the slot's pages.
+def gqa_decode_paged(cfg: ModelConfig, p, x, positions, store, layer,
+                     block_table, *, live=None, impl: str = "auto"):
+    """Paged decode step of one layer: append the new token's K/V through
+    the block table into the segment's stacked ``store`` at ``layer``, then
+    attend over the slot's pages of that layer.
 
     x: (B, 1, d); positions: (B,) absolute position of the new token;
+    store: leaves (L, P, page, ...); layer: scalar int32;
     block_table: (B, max_pages) int32 (-1 = unmapped); live: (B,) bool or
     None. ``impl`` picks the attention read: "pallas" /
     "pallas_interpret" force the kernel, "xla" the pure-jnp reference,
-    "auto" resolves per backend (kernels/ops.py).
+    "auto" resolves per backend (kernels/ops.py). Returns (y, store).
     """
     from repro.kernels import ops
 
@@ -426,17 +437,17 @@ def gqa_decode_paged(cfg: ModelConfig, p, x, positions, cache, block_table,
     quantized = cfg.kv_cache_dtype == "int8"
     q, k_new, v_new = _project_qkv(cfg, p, x, positions[:, None])
     with jax.named_scope("sprout.kv_write"):
-        cache = _paged_cache_write(cache, k_new, v_new, positions,
+        store = _paged_cache_write(store, layer, k_new, v_new, positions,
                                    block_table, live, quantized)
     # append-only pages: validity == index < length, causality is implicit
     lengths = positions + 1
     out = ops.paged_attention(
-        q[:, 0], cache["k"], cache["v"], block_table, lengths,
-        cache.get("k_scale"), cache.get("v_scale"), impl=impl)
+        q[:, 0], store["k"], store["v"], block_table, lengths, layer,
+        store.get("k_scale"), store.get("v_scale"), impl=impl)
     y = out.reshape(B, 1, h * dh) @ p["wo"]
     if cfg.use_bias:
         y = y + p["bo"]
-    return y, cache
+    return y, store
 
 
 # ======================================================================

@@ -220,7 +220,8 @@ def _fill_gqa_cache(cfg: ModelConfig, cache, k, v, kpos, window: int = 0):
 
 def block_apply(cfg: ModelConfig, kind: str, p, x, positions, mode: str,
                 cache, *, max_len: int = 0, lengths=None, enc_out=None,
-                block_table=None, live=None, paged_impl: str = "auto"):
+                block_table=None, live=None, paged_impl: str = "auto",
+                layer=None):
     """Returns (x_out, cache_out, aux).
 
     ``positions``: (B,S) for train/prefill, (B,) for decode.
@@ -228,7 +229,8 @@ def block_apply(cfg: ModelConfig, kind: str, p, x, positions, mode: str,
     ``max_len``: decode-cache capacity to allocate at prefill.
     ``enc_out``: (B, enc_seq, d) encoder output for xdec train/prefill.
     ``block_table``: (B, max_pages) int32 — switches decode attention onto
-    the paged path (``cache`` is then a page store, not a per-slot cache);
+    the paged path (``cache`` is then the segment's whole layer-stacked
+    page store and ``layer`` this block's index in it);
     ``live``/``paged_impl`` predicate dead-lane page writes and pick the
     paged attention implementation.
     """
@@ -264,7 +266,7 @@ def block_apply(cfg: ModelConfig, kind: str, p, x, positions, mode: str,
         if mode == "decode":
             if block_table is not None:
                 a, new_attn_cache = A.gqa_decode_paged(
-                    cfg, p["attn"], h, positions, cache, block_table,
+                    cfg, p["attn"], h, positions, cache, layer, block_table,
                     live=live, impl=paged_impl)
             elif is_mla:
                 a, new_attn_cache = A.mla_decode(cfg, p["attn"], h, positions, cache
@@ -353,15 +355,18 @@ def block_apply(cfg: ModelConfig, kind: str, p, x, positions, mode: str,
 
 
 def block_apply_chunk(cfg: ModelConfig, kind: str, p, x, positions, valid,
-                      lane, cache, *, block_table=None):
+                      lane, cache, *, block_table=None, layer=None):
     """One block over a single lane's prompt chunk (1, C, d). Attention
     appends the chunk's K/V to the lane's cache and attends causally over
     everything written so far; the FFN is position-wise as usual. Only
-    dense / moe kinds reach here (``chunked_prefill_supported``)."""
+    dense / moe kinds reach here (``chunked_prefill_supported``). Paged:
+    ``cache`` is the segment's stacked store and ``layer`` this block's
+    index in it."""
     h = apply_norm(cfg, p["ln1"], x)
     with jax.named_scope("sprout.attention"):
         a, cache = A.gqa_chunk_append(cfg, p["attn"], h, positions, valid,
-                                      lane, cache, block_table=block_table)
+                                      lane, cache, block_table=block_table,
+                                      layer=layer)
     x = x + a
     with jax.named_scope("sprout.mlp"):
         h2 = apply_norm(cfg, p["ln2"], x)
@@ -393,13 +398,22 @@ def prefill_chunk_step(cfg: ModelConfig, params, tokens, pos0, clen, lane,
 
     new_caches = []
     for i, (kind, n) in enumerate(_seg_kinds(cfg)):
-        def body(x, per_layer, kind=kind):
-            p, c = per_layer
-            x2, c2 = block_apply_chunk(cfg, kind, p, x, positions, valid,
-                                       lane, c, block_table=block_table)
-            return x2, c2
+        if block_table is not None:
+            def paged(p, x, store, layer, kind=kind):
+                x2, store = block_apply_chunk(
+                    cfg, kind, p, x, positions, valid, lane, store,
+                    block_table=block_table, layer=layer)
+                return x2, store, {}
 
-        x, c2 = jax.lax.scan(body, x, (params["segs"][i], cache[i]))
+            x, c2, _ = _scan_paged_store(paged, x, params["segs"][i],
+                                         cache[i])
+        else:
+            def body(x, per_layer, kind=kind):
+                p, c = per_layer
+                return block_apply_chunk(cfg, kind, p, x, positions, valid,
+                                         lane, c)
+
+            x, c2 = jax.lax.scan(body, x, (params["segs"][i], cache[i]))
         new_caches.append(c2)
 
     with jax.named_scope("sprout.lm_head"):
@@ -413,15 +427,49 @@ def prefill_chunk_step(cfg: ModelConfig, params, tokens, pos0, clen, lane,
 # segment scan
 # ======================================================================
 
+def _scan_paged_store(layer_fn, x, stacked_p, store):
+    """Scan a segment's layers with its stacked page store in the carry.
+
+    ``layer_fn(p, x, store, layer) -> (x, store, aux)`` runs one layer:
+    it writes that layer's tokens into ``store`` at [layer, page, offset]
+    and reads its pages through the layer index. The store is never sliced
+    per layer nor restacked as a scan output, so the program updates the
+    (donated) buffer in place. Returns (x, store, stacked aux).
+    """
+    n = jax.tree_util.tree_leaves(stacked_p)[0].shape[0]
+
+    def body(carry, per_layer):
+        x, store = carry
+        p, layer = per_layer
+        x, store, aux = layer_fn(p, x, store, layer)
+        return (x, store), aux
+
+    (x, store), auxs = jax.lax.scan(
+        body, (x, store), (stacked_p, jnp.arange(n, dtype=jnp.int32)))
+    return x, store, auxs
+
+
 def _seg_apply(cfg: ModelConfig, kind: str, stacked_p, x, positions, mode: str,
                stacked_cache, max_len: int, lengths=None, enc_out=None,
                block_table=None, live=None, paged_impl: str = "auto"):
     """Scan one segment. Returns (x, new_stacked_cache, stacked_aux).
 
-    ``block_table``/``live`` are shared across the segment's layers (scan
-    constants): each layer's page store is its own scanned cache slice, but
-    one page id addresses the same slot range in every layer.
+    Paged decode (``block_table`` given) carries the segment's stacked page
+    store through the layer scan (``_scan_paged_store``): each layer writes
+    its token at [layer, page, offset] and the kernel reads that layer's
+    pages by index; it always scans, whatever ``cfg.scan_layers`` says.
+    ``block_table``/``live`` are shared across the layers (scan
+    constants): one page id addresses the same slot range in every layer.
+    Every other cache is scanned per layer as ``xs`` and restacked as
+    ``ys``.
     """
+    if block_table is not None:
+        def paged(p, x, store, layer):
+            return block_apply(cfg, kind, p, x, positions, mode, store,
+                               block_table=block_table, live=live,
+                               paged_impl=paged_impl, layer=layer)
+
+        return _scan_paged_store(paged, x, stacked_p, stacked_cache)
 
     def body(x, per_layer):
         if mode == "decode":
@@ -430,8 +478,7 @@ def _seg_apply(cfg: ModelConfig, kind: str, stacked_p, x, positions, mode: str,
             p, c = per_layer, None
         x2, c2, aux = block_apply(cfg, kind, p, x, positions, mode, c,
                                   max_len=max_len, lengths=lengths,
-                                  enc_out=enc_out, block_table=block_table,
-                                  live=live, paged_impl=paged_impl)
+                                  enc_out=enc_out)
         return x2, (c2, aux)
 
     if cfg.remat != "none" and mode == "train":

@@ -56,6 +56,11 @@ def test_flash_kernel_property(T, D, group, causal):
                                rtol=3e-5, atol=3e-5)
 
 
+def _stacked(key, shape, n_layers, dtype=jnp.float32):
+    """A layer-stacked store (n_layers, *shape) of normal draws."""
+    return jax.random.normal(key, (n_layers, *shape)).astype(dtype)
+
+
 @pytest.mark.parametrize("B,H,KVH,D,P,page,maxp", [
     (3, 8, 2, 64, 16, 32, 4),
     (2, 4, 4, 128, 8, 64, 2),
@@ -65,15 +70,15 @@ def test_paged_kernel_sweep(B, H, KVH, D, P, page, maxp):
     key = jax.random.PRNGKey(B * 100 + H)
     ks = jax.random.split(key, 3)
     q = jax.random.normal(ks[0], (B, H, D))
-    kp = jax.random.normal(ks[1], (P, page, KVH, D))
-    vp = jax.random.normal(ks[2], (P, page, KVH, D))
+    kp = _stacked(ks[1], (P, page, KVH, D), 1)
+    vp = _stacked(ks[2], (P, page, KVH, D), 1)
     rng = np.random.default_rng(0)
     bt = rng.permutation(P)[: B * maxp].reshape(B, maxp).astype(np.int32)
     lengths = rng.integers(1, page * maxp, B).astype(np.int32)
     out = paged_attention(q, kp, vp, jnp.asarray(bt), jnp.asarray(lengths),
-                          interpret=True)
+                          jnp.int32(0), interpret=True)
     want = ref.paged_attention_ref(q, kp, vp, jnp.asarray(bt),
-                                   jnp.asarray(lengths))
+                                   jnp.asarray(lengths), jnp.int32(0))
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
 
@@ -83,20 +88,60 @@ def test_paged_kernel_int8():
     B, H, KVH, D, P, page, maxp = 2, 8, 2, 64, 8, 32, 3
     ks = jax.random.split(key, 3)
     q = jax.random.normal(ks[0], (B, H, D))
-    kp = jax.random.normal(ks[1], (P, page, KVH, D))
-    vp = jax.random.normal(ks[2], (P, page, KVH, D))
+    kp = _stacked(ks[1], (P, page, KVH, D), 1)
+    vp = _stacked(ks[2], (P, page, KVH, D), 1)
     kq, ksc = quantize_kv(kp.reshape(P * page, 1, KVH, D))
     vq, vsc = quantize_kv(vp.reshape(P * page, 1, KVH, D))
-    kq = kq.reshape(P, page, KVH, D).astype(jnp.float32)
-    vq = vq.reshape(P, page, KVH, D).astype(jnp.float32)
-    ksc = ksc.reshape(P, page, KVH)
-    vsc = vsc.reshape(P, page, KVH)
+    kq = kq.reshape(kp.shape).astype(jnp.float32)
+    vq = vq.reshape(vp.shape).astype(jnp.float32)
+    ksc = ksc.reshape(1, P, page, KVH)
+    vsc = vsc.reshape(1, P, page, KVH)
     bt = jnp.asarray([[0, 1, 2], [3, 4, 5]], jnp.int32)
     lengths = jnp.asarray([70, 96], jnp.int32)
-    out = paged_attention(q, kq, vq, bt, lengths, ksc, vsc, interpret=True)
-    want = ref.paged_attention_ref(q, kp, vp, bt, lengths)
+    out = paged_attention(q, kq, vq, bt, lengths, jnp.int32(0), ksc, vsc,
+                          interpret=True)
+    want = ref.paged_attention_ref(q, kp, vp, bt, lengths, jnp.int32(0))
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=0.05, atol=0.05)  # int8 quant noise
+
+
+@pytest.mark.parametrize("impl", ["pallas_interpret", "xla"])
+@pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
+@pytest.mark.parametrize("layer", [0, 2, 4])
+def test_paged_kernel_stacked_layer_matches_its_slice(layer, kv_dtype, impl):
+    """The stacked-store kernel at layer 0, a middle layer and the last
+    layer reads that layer's pages alone: it matches the reference run on
+    that layer's slice as a one-layer store, bit for bit in the reference
+    and to kernel precision in the interpreted kernel."""
+    L, B, H, KVH, D, P, page, maxp = 5, 3, 8, 2, 64, 12, 16, 4
+    ks = jax.random.split(jax.random.PRNGKey(21), 5)
+    q = jax.random.normal(ks[0], (B, H, D)).astype(jnp.bfloat16)
+    if kv_dtype == "int8":
+        kp = jax.random.randint(ks[1], (L, P, page, KVH, D), -127, 128,
+                                jnp.int8)
+        vp = jax.random.randint(ks[2], (L, P, page, KVH, D), -127, 128,
+                                jnp.int8)
+        scales = (jax.random.uniform(ks[3], (L, P, page, KVH), jnp.float32,
+                                     0.005, 0.02),
+                  jax.random.uniform(ks[4], (L, P, page, KVH), jnp.float32,
+                                     0.005, 0.02))
+    else:
+        kp = _stacked(ks[1], (P, page, KVH, D), L, jnp.bfloat16)
+        vp = _stacked(ks[2], (P, page, KVH, D), L, jnp.bfloat16)
+        scales = ()
+    rng = np.random.default_rng(layer)
+    bt = jnp.asarray(rng.permutation(P)[: B * maxp].reshape(B, maxp),
+                     jnp.int32)
+    lengths = jnp.asarray([1, 17, page * maxp], jnp.int32)
+    out = ops.paged_attention(q, kp, vp, bt, lengths, jnp.int32(layer),
+                              *scales, impl=impl)
+    one = lambda a: a[layer][None]                    # noqa: E731
+    want = ref.paged_attention_ref(q, one(kp), one(vp), bt, lengths,
+                                   jnp.int32(0), *map(one, scales))
+    tol = 0 if impl == "xla" else 2e-2
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=tol, atol=tol)
 
 
 @pytest.mark.parametrize("N,d,block,res", [(512, 128, 128, True),

@@ -11,6 +11,7 @@ import pytest
 
 from repro.configs import reduced
 from repro.core.energy import LLAMA2_13B, A100_40GB, EnergyModel
+from repro.models import attention as A
 from repro.models import model as MD
 from repro.models.attention import quantize_kv
 from repro.serving import ByteTokenizer, InferenceEngine
@@ -122,6 +123,14 @@ def _oracle_from_gather(pc: PagedKVCache, q):
     return np.stack(outs)
 
 
+def _in_stack(store, layer, n_layers=3):
+    """``store`` as layer ``layer`` of a stacked store whose other layers
+    hold noise, so a kernel that read the wrong layer would fail."""
+    noise = jax.random.normal(jax.random.PRNGKey(123),
+                              (n_layers, *store.shape)).astype(store.dtype)
+    return noise.at[layer].set(store)
+
+
 @pytest.mark.pallas
 @pytest.mark.parametrize("lengths", [
     (15, 16, 17),     # straddle below / exactly on / above a page boundary
@@ -138,7 +147,8 @@ def test_paged_kernel_matches_gather_oracle(lengths):
         pc.write_prompt(b, kb, kb * 0.25 + 1.0)
     q = jax.random.normal(jax.random.fold_in(key, 99), (B, H, dh))
     bt, ln = pc.device_tables()
-    out = paged_attention(q, pc.k, pc.v, bt, ln, interpret=True)
+    out = paged_attention(q, _in_stack(pc.k, 1), _in_stack(pc.v, 1), bt, ln,
+                          jnp.int32(1), interpret=True)
     want = _oracle_from_gather(pc, q)
     np.testing.assert_allclose(np.asarray(out), want, rtol=2e-5, atol=2e-5)
 
@@ -156,7 +166,8 @@ def test_paged_kernel_predicated_empty_pages_no_dma():
     assert (pc.block_table[0] >= 0).sum() == 1       # 7 unmapped entries
     q = jax.random.normal(jax.random.fold_in(key, 1), (2, H, dh))
     bt, ln = pc.device_tables()
-    out = paged_attention(q, pc.k, pc.v, bt, ln, interpret=True)
+    out = paged_attention(q, _in_stack(pc.k, 2), _in_stack(pc.v, 2), bt, ln,
+                          jnp.int32(2), interpret=True)
     want = _oracle_from_gather(pc, q[:1])
     np.testing.assert_allclose(np.asarray(out)[:1], want, rtol=2e-5,
                                atol=2e-5)
@@ -178,12 +189,58 @@ def test_paged_kernel_int8_parity_with_fp_oracle():
     q = jax.random.normal(jax.random.fold_in(key, 9), (B, H, dh))
     bt, ln = pc.device_tables()
     out = paged_attention(
-        q, kq.reshape(pc.k.shape).astype(jnp.float32),
-        vq.reshape(pc.v.shape).astype(jnp.float32), bt, ln,
-        ks.reshape(*pc.k.shape[:2], nkv), vs.reshape(*pc.v.shape[:2], nkv),
-        interpret=True)
+        q, _in_stack(kq.reshape(pc.k.shape).astype(jnp.float32), 0),
+        _in_stack(vq.reshape(pc.v.shape).astype(jnp.float32), 0), bt, ln,
+        jnp.int32(0), _in_stack(ks.reshape(*pc.k.shape[:2], nkv), 0),
+        _in_stack(vs.reshape(*pc.v.shape[:2], nkv), 0), interpret=True)
     want = _oracle_from_gather(pc, q)
     np.testing.assert_allclose(np.asarray(out), want, rtol=0.05, atol=0.05)
+
+
+# ======================================================================
+# in-place writes into the layer-stacked store
+# ======================================================================
+
+@pytest.mark.parametrize("kv_int8", [False, True])
+def test_stacked_store_writes_touch_only_their_layer(small_model, kv_int8):
+    """A decode append and a chunk append at [layer, page, offset] change
+    only that layer's mapped pages: every other layer's pages stay
+    bit-identical, and so do the written layer's other pages. Dead lanes
+    and unmapped entries write nowhere."""
+    cfg, params = small_model
+    if kv_int8:
+        cfg = cfg.replace(kv_cache_dtype="int8")
+    L, P, ps, layer = 4, 6, 16, 2
+    one = jax.tree.map(lambda a: a[0],
+                       MD.init_paged_cache(cfg, P, ps)[0])
+    key = jax.random.PRNGKey(4)
+    store = {nm: (jax.random.normal(jax.random.fold_in(key, i),
+                                    (L, *a.shape)) * 50).astype(a.dtype)
+             for i, (nm, a) in enumerate(sorted(one.items()))}
+    p = jax.tree.map(lambda a: a[layer], params["segs"][0])["attn"]
+    d = cfg.d_model
+    bt = jnp.asarray([[3, 1, -1, -1], [0, -1, -1, -1], [5, 2, -1, -1]],
+                     jnp.int32)
+    positions = jnp.asarray([17, 40, 5])     # row 1: unmapped entry
+    live = jnp.asarray([True, True, False])  # row 2: dead lane
+    x = jax.random.normal(key, (3, 1, d)).astype(cfg.compute_dtype)
+    _, dec = A.gqa_decode_paged(cfg, p, x, positions, store,
+                                jnp.int32(layer), bt, live=live, impl="xla")
+    xc = jax.random.normal(key, (1, 8, d)).astype(cfg.compute_dtype)
+    _, chk = A.gqa_chunk_append(cfg, p, xc, 24 + jnp.arange(8),
+                                jnp.arange(8) < 6, jnp.int32(2), store,
+                                block_table=bt, layer=jnp.int32(layer))
+    # decode: row 0 lands at page 1, offset 1; chunk: lane 2's valid rows
+    # (positions 24..29) at page 2, offsets 8..13
+    for new, pg, offs in ((dec, 1, [1]), (chk, 2, list(range(8, 14)))):
+        keep = np.ones((L, P, ps), bool)
+        keep[layer, pg, offs] = False
+        for nm in store:
+            np.testing.assert_array_equal(np.asarray(store[nm])[keep],
+                                          np.asarray(new[nm])[keep],
+                                          err_msg=nm)
+        assert (np.asarray(store["k"])[layer, pg, offs]
+                != np.asarray(new["k"])[layer, pg, offs]).any(-1).any(-1).all()
 
 
 # ======================================================================

@@ -10,22 +10,31 @@ Shapes are granite_3_2b's published widths (32 query heads, 8 KV heads,
 head dim 64, d_model 2048) at the engine's 128-token pages. The topology
 is described inside a fixture, never at import: only one process at a time
 may load the TPU library, and every xdist worker imports this file.
+
+The engine's fused paged programs are compiled here too, at those widths
+with 4 layers: they must update the carried page store in place, with no
+whole-store broadcast or dynamic-update-slice and no whole-store
+temporaries beyond the layout copies at the loop's entry and exit.
 """
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import get_config
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.paged_attention import paged_attention
 from repro.kernels.rmsnorm import fused_rmsnorm
+from repro.models import model as MD
+from repro.serving.engine import InferenceEngine
 
 B, H, KVH, D, D_MODEL = 8, 32, 8, 64, 2048
-PAGE, MAX_PAGES, N_PAGES = 128, 8, 64
+PAGE, MAX_PAGES, N_PAGES, N_LAYERS = 128, 8, 64, 4
 
 
 @pytest.fixture(scope="module")
@@ -58,11 +67,12 @@ def _compile(fn, *shapes, sharding):
 @pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
 def test_paged_attention_compiles_for_v5e(one_chip, kv_dtype):
     kv = jnp.dtype(kv_dtype)
-    shapes = [((B, H, D), jnp.bfloat16), ((N_PAGES, PAGE, KVH, D), kv),
-              ((N_PAGES, PAGE, KVH, D), kv), ((B, MAX_PAGES), jnp.int32),
-              ((B,), jnp.int32)]
+    store = (N_LAYERS, N_PAGES, PAGE, KVH, D)
+    shapes = [((B, H, D), jnp.bfloat16), (store, kv), (store, kv),
+              ((B, MAX_PAGES), jnp.int32), ((B,), jnp.int32),
+              ((), jnp.int32)]
     if kv == jnp.int8:
-        shapes += [((N_PAGES, PAGE, KVH), jnp.float32)] * 2
+        shapes += [(store[:-1], jnp.float32)] * 2
     _compile(paged_attention, *shapes, sharding=one_chip)
 
 
@@ -77,3 +87,60 @@ def test_fused_rmsnorm_compiles_for_v5e(one_chip):
     _compile(fused_rmsnorm, ((256, D_MODEL), jnp.bfloat16),
              ((D_MODEL,), jnp.float32), ((256, D_MODEL), jnp.bfloat16),
              sharding=one_chip)
+
+
+@pytest.mark.parametrize("k,chunk", [(8, 0), (2, PAGE)])
+def test_fused_paged_program_updates_store_in_place(one_chip, monkeypatch,
+                                                    k, chunk):
+    """The engine's k = 8 paged decode program, and its mixed program with
+    one prompt chunk per step, at granite widths with 4 layers, 64 pages
+    and 16 lanes, the cache donated: no op builds or rewrites a whole
+    stacked store, the donated store still aliases its output, and the
+    temporaries stay under 2.5 K+V stores (the slice-and-restack scan kept
+    about 6). The mixed program runs two steps: a one-step scan lets the
+    compiler stage this small store in fast memory, which no real size
+    allows."""
+    cfg = get_config("granite_3_2b").replace(n_layers=N_LAYERS)
+    params = jax.eval_shape(lambda key: MD.init_model(cfg, key),
+                            jax.random.PRNGKey(0))
+    # shapes only: nothing is allocated for a device that is not there
+    init = MD.init_paged_cache
+    monkeypatch.setattr(MD, "init_paged_cache",
+                        lambda *a: jax.eval_shape(lambda: init(*a)))
+    bs = 16
+    eng = InferenceEngine(cfg, params, n_slots=bs, max_len=2048, paged=True,
+                          page_size=PAGE, n_pages=N_PAGES,
+                          paged_impl="pallas", prefill_chunk=PAGE)
+    fn, _ = eng._fused_for(k, "greedy", bs, chunk)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    ints = ("last", "pos", "gc", "max_new", "topk", "rows")
+    state = {n: spec((bs,), jnp.int32) for n in ints}
+    state.update(live=spec((bs,), bool), ok=spec((bs,), bool),
+                 temp=spec((bs,), jnp.float32), topp=spec((bs,), jnp.float32),
+                 key=spec((2,), jnp.uint32))
+    chunk_xs = None
+    if chunk:
+        state["chunk_lane"] = spec((), jnp.int32)
+        chunk_xs = (spec((k, chunk), jnp.int32), spec((k,), jnp.int32),
+                    spec((k,), jnp.int32), spec((k,), bool))
+    as_spec = lambda a: spec(a.shape, a.dtype)           # noqa: E731
+    compiled = fn.lower(
+        jax.tree.map(as_spec, params), jax.tree.map(as_spec, eng.cache),
+        spec((bs, eng.pages.max_pages), jnp.int32), state,
+        chunk_xs).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, "the paged kernel did not lower"
+    store = eng.cache[0]["k"]
+    assert store.shape == (N_LAYERS, N_PAGES, PAGE, KVH, D)
+    shape = re.escape("bf16[" + ",".join(map(str, store.shape)) + "]")
+    whole = re.findall(shape + r"\S*\s+(broadcast|dynamic-update-slice)\(",
+                       text)
+    assert not whole, f"store-shaped ops: {whole}"
+    kv_bytes = 2 * store.size * store.dtype.itemsize
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= kv_bytes, "the store is not donated"
+    assert mem.temp_size_in_bytes <= 2.5 * kv_bytes, \
+        f"temporaries {mem.temp_size_in_bytes / kv_bytes:.2f} K+V stores"
