@@ -1,20 +1,28 @@
 """Profiler trace -> plain events -> the numbers per-layer readers use.
 
 ``normalize`` reads the ``.xplane.pb`` the JAX profiler writes, keeping
-three lists of ``[name, start_ns, duration_ns]`` on the trace's one clock:
-``device_ops`` (the "XLA Ops" lines of the TPU planes), ``device_modules``
-(their "XLA Modules" lines: one event per compiled program run) and
-``host_spans`` (the benchmark's own ``bench.*`` TraceAnnotation spans).
-Everything after that works on the plain lists, so the reduction is
-checked on a small recorded trace without a chip.
+three lists on the trace's one clock: ``device_ops`` (the "XLA Ops" lines
+of the TPU planes) and ``device_modules`` (their "XLA Modules" lines: one
+event per compiled program run), each event ``[name, start_ns,
+duration_ns, device]`` with the device's index from its plane
+(``/device:TPU:<index>``), and ``host_spans``, ``[name, start_ns,
+duration_ns]`` for the benchmark's own ``bench.*`` TraceAnnotation spans
+and the program's ``sprout.*`` spans. Everything after that works on the
+plain lists, so the reduction is checked on a small recorded trace without
+a chip.
+
+Device times are per device: a time is the mean over the devices that ran
+an operation in the trace, so that on one device it is that device's
+time, and on a tensor-parallel cell one chip's share of the work.
 """
 from __future__ import annotations
 
 import glob
 import re
-from typing import Dict, List, Tuple
+from typing import Dict, List, Optional, Tuple
 
-_DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
+_DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
+HOST_PREFIXES = ("bench.", "sprout.")
 
 
 def normalize(trace_dir: str) -> Dict[str, list]:
@@ -25,20 +33,31 @@ def normalize(trace_dir: str) -> Dict[str, list]:
     pd = ProfileData.from_file(paths[-1])
     out = {"device_ops": [], "device_modules": [], "host_spans": []}
     for plane in pd.planes:
-        if _DEVICE_PLANE.match(plane.name):
+        dev = _DEVICE_PLANE.match(plane.name)
+        if dev:
             for line in plane.lines:
                 key = {"XLA Ops": "device_ops",
                        "XLA Modules": "device_modules"}.get(line.name)
                 if key:
                     out[key].extend([e.name, float(e.start_ns),
-                                     float(e.duration_ns)]
+                                     float(e.duration_ns), int(dev[1])]
                                     for e in line.events)
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 out["host_spans"].extend(
                     [e.name, float(e.start_ns), float(e.duration_ns)]
-                    for e in line.events if e.name.startswith("bench."))
+                    for e in line.events
+                    if e.name.startswith(HOST_PREFIXES))
     return out
+
+
+def devices(tr: dict) -> List[int]:
+    """The devices that ran an operation in the trace."""
+    return sorted({ev[3] for ev in tr["device_ops"]})
+
+
+def _per_device(total: float, tr: dict) -> float:
+    return total / max(len(devices(tr)), 1)
 
 
 def union(intervals) -> List[Tuple[float, float]]:
@@ -52,8 +71,11 @@ def union(intervals) -> List[Tuple[float, float]]:
     return [(s, e) for s, e in merged]
 
 
-def busy_intervals(tr: dict) -> List[Tuple[float, float]]:
-    return union((s, d) for _, s, d in tr["device_ops"])
+def busy_intervals(tr: dict, device: Optional[int] = None
+                   ) -> List[Tuple[float, float]]:
+    """When one device (or, with ``device`` None, any) runs an op."""
+    return union((s, d) for _, s, d, dev in tr["device_ops"]
+                 if device is None or dev == device)
 
 
 def overlap_ns(intervals, t0: float, t1: float) -> float:
@@ -71,13 +93,15 @@ def window(tr: dict) -> Tuple[float, float]:
 
 
 def busy_s(tr: dict) -> float:
+    """Seconds of the window in which a device ran an op, per device."""
     t0, t1 = window(tr)
-    return overlap_ns(busy_intervals(tr), t0, t1) / 1e9
+    return _per_device(sum(overlap_ns(busy_intervals(tr, dev), t0, t1)
+                           for dev in devices(tr)), tr) / 1e9
 
 
 def module_s(tr: dict, prefix: str) -> float:
-    return sum(d for n, _, d in tr["device_modules"]
-               if n.startswith(prefix)) / 1e9
+    return _per_device(sum(d for n, _, d, _ in tr["device_modules"]
+                           if n.startswith(prefix)), tr) / 1e9
 
 
 # ops that only enclose others: their time is their body's
@@ -92,23 +116,24 @@ def op_kind(event_name: str) -> str:
 
 
 def op_s(tr: dict, kind: str) -> float:
-    return sum(d for n, _, d in tr["device_ops"] if op_kind(n) == kind) / 1e9
+    return _per_device(sum(d for n, _, d, _ in tr["device_ops"]
+                           if op_kind(n) == kind), tr) / 1e9
 
 
 def top_ops(tr: dict, n: int = 10) -> List[list]:
-    """Device time by op kind, enclosing loops left out."""
+    """Device time per device by op kind, enclosing loops left out."""
     by: Dict[str, float] = {}
-    for name, _, d in tr["device_ops"]:
+    for name, _, d, _ in tr["device_ops"]:
         k = op_kind(name)
         if k not in _ENCLOSING:
             by[k] = by.get(k, 0.0) + d
-    return [[k, v / 1e9] for k, v in
+    return [[k, _per_device(v, tr) / 1e9] for k, v in
             sorted(by.items(), key=lambda kv: -kv[1])[:n]]
 
 
 def idle_gaps(tr: dict, n: int = 10) -> List[list]:
-    """The longest device-idle gaps inside the window, each named by the
-    innermost benchmark host span open at its midpoint."""
+    """The longest gaps inside the window in which no device runs an op,
+    each named by the innermost host span open at its midpoint."""
     t0, t1 = window(tr)
     busy = [(max(s, t0), min(e, t1)) for s, e in busy_intervals(tr)
             if e > t0 and s < t1]

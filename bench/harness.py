@@ -1,10 +1,14 @@
 """One run of one cell: set-up, a measured window through the gateway's
 own calls, a traced window on request, and the check against the
-float32 reference. ``bench/run.py`` is the command; see PERF.md.
+float32 reference the configuration names. ``bench/run.py`` is the
+command; see PERF.md. A cell runs on ``chips`` devices, which equal its
+engine's ``tp_degree``: the weights are drawn straight into the engine's
+tensor-parallel placement.
 """
 from __future__ import annotations
 
 import argparse
+import contextlib
 import dataclasses
 import gc
 import json
@@ -19,7 +23,6 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from bench import devtrace, loadgen, peaks, spec, weights
-from bench.reference.dense_decoder import DenseDecoder
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 REGION, CARBON_TRACE = "CA", "jun"
@@ -67,6 +70,7 @@ class Run:
     cell: spec.Cell
     model: dict
     peaks: dict
+    counts: object        # the configuration's counts module
     counters: Dict[str, float] = dataclasses.field(default_factory=dict)
     submit_s: List[float] = dataclasses.field(default_factory=list)
     trace: Optional[dict] = None
@@ -95,14 +99,40 @@ def model_config(cell: spec.Cell):
     return ModelConfig(**cell.config["model"])
 
 
-def make_weights(cfg, seed: int):
+def param_shardings(cfg, engine: dict, shapes):
+    """The engine's tensor-parallel placement of the weights (None on one
+    chip): ``serving_shard_spec`` on the mesh the engine builds."""
+    if engine["tp_degree"] == 1:
+        return None
+    import jax
+    from repro.launch.mesh import make_tp_mesh
+    from repro.launch.sharding import serving_shard_spec
+    from repro.models import model as MD
+    cache = jax.eval_shape(lambda: MD.init_paged_cache(
+        cfg, engine["n_pages"], engine["page_size"]))
+    return serving_shard_spec(cfg, make_tp_mesh(engine["tp_degree"]),
+                              shapes, cache, paged=True).params
+
+
+def make_weights(cfg, seed: int, engine: dict):
+    """Drawn on the device in one program, each leaf where the engine
+    keeps it: no device ever holds the whole of a sharded leaf."""
     import jax
     from repro.models import model as MD
     shapes = jax.eval_shape(lambda k: MD.init_model(cfg, k),
                             jax.random.PRNGKey(0))
-    params = weights.make_params(shapes, seed)
+    params = weights.make_params(shapes, seed,
+                                 param_shardings(cfg, engine, shapes))
     jax.block_until_ready(params)
     return params
+
+
+def on_mesh(eng):
+    """The engine's mesh as the current one, as its own dispatch sets it
+    (a tensor-parallel engine's paged kernel finds the mesh there)."""
+    import jax
+    return jax.set_mesh(eng.shard_spec.mesh) if eng.shard_spec \
+        else contextlib.nullcontext()
 
 
 def make_gateway(cfg, params, cell: spec.Cell, seed: int, entry_points=None):
@@ -182,18 +212,25 @@ def warm_fused(eng, shapes, clock: CompileClock) -> None:
     first one had to compile (a cold cache), the rest are compiled into
     the persistent cache first, several at a time, and then loaded."""
     import jax
+
+    def run(call, lower: bool = False):
+        fn, args = call
+        # the arguments are made outside the mesh, as the engine makes its
+        # own: arrays made under a mesh are placed on it and compile apart
+        a = args(eng)
+        with on_mesh(eng):
+            return fn.lower(*a).compile() if lower else fn(*a)
+
     calls = [dead_call(eng, *shape) for shape in shapes]
     c0 = clock.seconds
-    fn, args = calls[0]
-    eng.cache, *_ = fn(*args(eng))
+    eng.cache, *_ = run(calls[0])
     jax.block_until_ready(eng.cache)
     if clock.seconds - c0 > COLD_COMPILE_S:
         from concurrent.futures import ThreadPoolExecutor
         with ThreadPoolExecutor(PRECOMPILE_THREADS) as ex:
-            list(ex.map(lambda fa: fa[0].lower(*fa[1](eng)).compile(),
-                        calls[1:]))
-    for fn, args in calls[1:]:
-        eng.cache, *_ = fn(*args(eng))
+            list(ex.map(lambda call: run(call, lower=True), calls[1:]))
+    for call in calls[1:]:
+        eng.cache, *_ = run(call)
     jax.block_until_ready(eng.cache)
 
 
@@ -342,10 +379,11 @@ def serve(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     mix = cell.mix
     run = Run(cell, cell.config["model"],
               peaks.peaks_for(jax.devices()[0].device_kind)
-              if jax.devices()[0].platform == "tpu" else {})
+              if jax.devices()[0].platform == "tpu" else {},
+              spec.counts(cell))
     cfg = model_config(cell)
     t0 = time.monotonic()
-    params = make_weights(cfg, seed)
+    params = make_weights(cfg, seed, cell.config["engine"])
     gw, eng = make_gateway(cfg, params, cell, seed, entry_points)
     t1 = time.monotonic()
     log(f"set-up: weights and engine {t1 - t0:.3f} s, "
@@ -544,12 +582,13 @@ def served_samples(s: dict, rids: List[int]):
     return out
 
 
-def logit_gaps(model: dict, params, samples, quant: str = "") -> list:
-    """Per sample, per served token: the reference's best logit minus the
-    reference's logit of the token served (``quant``: of the token the
+def logit_gaps(reference, model: dict, params, samples,
+               quant: str = "") -> list:
+    """Per sample, per served token: the ``reference`` class's best logit
+    minus its logit of the token served (``quant``: of the token the
     control puts first)."""
-    ref = DenseDecoder(model, params)
-    ctl = DenseDecoder(model, params, quant=quant) if quant else None
+    ref = reference(model, params)
+    ctl = reference(model, params, quant=quant) if quant else None
     gaps = []
     for prompt, served, _ in samples:
         seq = prompt + served[:-1]
@@ -571,7 +610,8 @@ def check(cell: spec.Cell, s: dict, seed: int,
     if "exact" not in s:
         s["exact"] = exact_counts(cell, s, seed)
     rids, samples = s["rids"], s["samples"]
-    per = logit_gaps(reference_model(cell), s["params"], samples, control)
+    per = logit_gaps(spec.reference(cell), reference_model(cell),
+                     s["params"], samples, control)
     drv = s["drv"]
     for rid, (p, served, _), g in zip(rids, samples, per):
         fin = drv.finished[rid]
@@ -643,6 +683,7 @@ def parse(argv):
 
 
 def device_info(cell: spec.Cell, allow_cpu: bool):
+    """The cell's devices, or None (with the reason on stderr)."""
     import jax
     devs = jax.devices()
     d = devs[0]
@@ -652,14 +693,17 @@ def device_info(cell: spec.Cell, allow_cpu: bool):
         print("bench: no TPU found; there is no CPU fallback",
               file=sys.stderr)
         return None
+    tp = cell.config["engine"]["tp_degree"]
+    if cell.chips != tp:
+        print(f"bench: {cell.name} asks for {cell.chips} chips at "
+              f"tp_degree {tp}; a cell runs one engine over all its chips, "
+              f"so chips must equal tp_degree", file=sys.stderr)
+        return None
     if len(devs) < cell.chips:
         print(f"bench: {cell.name} needs {cell.chips} chips, found "
               f"{len(devs)}", file=sys.stderr)
         return None
-    if cell.chips != 1 or cell.config["engine"]["tp_degree"] != 1:
-        print("bench: only one-chip cells are implemented", file=sys.stderr)
-        return None
-    return devs
+    return devs[:cell.chips]
 
 
 def main(argv=None, *, t_start: Optional[float] = None, root=None,
@@ -704,12 +748,15 @@ def main(argv=None, *, t_start: Optional[float] = None, root=None,
         f"late by mean {1e3 * statistics.fmean(lat):.3f} ms, max "
         f"{1e3 * max(lat):.3f} ms; directive mix of finished "
         f"{levels.tolist()}; counters {json.dumps(run.counters)}")
-    mem = devs[0].memory_stats() or {}
-    peak = int(mem.get("peak_bytes_in_use", 0))
-    log(f"memory: peak {peak} bytes of {mem.get('bytes_limit', 'unknown')}")
+    mems = [d.memory_stats() or {} for d in devs]
+    peaks_each = [int(m.get("peak_bytes_in_use", 0)) for m in mems]
+    peak = max(peaks_each)
+    log(f"memory: peak {peak} bytes of "
+        f"{mems[0].get('bytes_limit', 'unknown')}; per device {peaks_each}")
     metrics = per_layer(cell, run) if args.trace else end_to_end(cell, s)
     device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
-              "count": cell.chips, "memory_peak_bytes": peak}
+              "count": cell.chips, "memory_peak_bytes": peak,
+              "memory_peak_bytes_each": peaks_each}
     breakdown = None
     if args.trace:
         device["busy_s"] = devtrace.busy_s(run.trace)

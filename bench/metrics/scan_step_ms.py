@@ -1,5 +1,6 @@
 """Device time of the fused decode / mixed programs (module ``jit_fused``)
-per scan step they ran, in the traced window, milliseconds."""
+per scan step they ran, in the traced window, milliseconds; the time is
+the mean over the devices."""
 from bench import devtrace
 
 MODULE = "jit_fused"

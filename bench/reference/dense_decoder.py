@@ -17,6 +17,13 @@ names of the serving engine's parameter tree (its checkpoint format):
 on operands rounded to float8 e4m3 (per-row scales for activations,
 per-column for weights), the step below the bfloat16 the configurations
 serve in.
+
+The weights are used as they are placed: on a tensor-parallel cell they
+stay sharded over its chips, and the jitted layer and head follow their
+shardings. Memory: one layer's weights upcast to float32 at a time, and
+the head upcasts the whole embedding, ``vocab x d_model x 4`` bytes
+(granite_3_2b 0.40 GB, minicpm_2b 1.13 GB; divided over the chips that
+share the embedding's vocabulary rows).
 """
 from __future__ import annotations
 
@@ -114,3 +121,6 @@ class DenseDecoder:
                         self.params["norm"]["scale"], tok, self.dims[3],
                         self.quant)
         return np.asarray(out)
+
+
+Reference = DenseDecoder

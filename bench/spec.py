@@ -3,16 +3,22 @@
 A cell names a configuration (its ``file``) and a traffic mix
 (``bench/traffic/<mix>.json``); each per-layer metric is a reader in
 ``bench/metrics/<name>.py``, or ``<base>.py`` for ``<base>.<cell kind>``.
-Files are looked up under the checkout root first, then beside this file,
-so a cell defined entirely in another directory runs with the readers
-that ship here.
+The configuration names its plain reference (``reference.module``: a
+class ``Reference`` in ``bench/reference/<module>.py``) and the module
+that counts its operations and bytes (``counts``: ``bench/<counts>.py``,
+``counts`` where the key is absent). Files are looked up under the
+checkout root first, then beside this file, so a cell defined entirely in
+another directory runs with the readers that ship here, and a new
+architecture brings its reference and counts as new files.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 import pathlib
+from types import ModuleType
 from typing import Callable, List, Optional
 
 from bench import loadgen
@@ -67,6 +73,15 @@ def load_cell(root, workload: str) -> Cell:
                 e2e, per_layer)
 
 
+@functools.lru_cache(maxsize=None)
+def _module(path: pathlib.Path) -> ModuleType:
+    spec = importlib.util.spec_from_file_location(
+        f"bench_{path.parent.name}_{path.stem.replace('.', '_')}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def reader(root, metric: str) -> Callable:
     """The ``read(run)`` function of a per-layer metric."""
     root = pathlib.Path(root)
@@ -74,8 +89,22 @@ def reader(root, metric: str) -> Callable:
         _find(root, f"metrics/{metric.split('.')[0]}.py")
     if path is None:
         raise FileNotFoundError(f"no reader for metric {metric!r}")
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{path.stem.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _module(path).read
+
+
+def reference(cell: Cell) -> type:
+    """The plain reference class the configuration names."""
+    name = cell.config["reference"]["module"]
+    path = _find(cell.root, f"reference/{name}.py")
+    if path is None:
+        raise FileNotFoundError(f"no reference module {name!r}")
+    return _module(path).Reference
+
+
+def counts(cell: Cell) -> ModuleType:
+    """The module of operation and byte counts the configuration names."""
+    name = cell.config.get("counts", "counts")
+    path = _find(cell.root, f"{name}.py")
+    if path is None:
+        raise FileNotFoundError(f"no counts module {name!r}")
+    return _module(path)

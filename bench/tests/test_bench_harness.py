@@ -68,7 +68,8 @@ def test_benchmark_files_alone_exit_nonzero(tmp_path):
     assert not any(line.startswith("{") for line in p.stdout.splitlines())
 
 
-@pytest.mark.parametrize("name", ["granite_3_2b.chat", "minicpm_2b.batch"])
+@pytest.mark.parametrize("name", ["granite_3_2b.chat", "minicpm_2b.batch",
+                                  "granite_3_2b.batch"])
 def test_benchmark_cells_load(name):
     from bench import spec
     cell = spec.load_cell(ROOT, name)

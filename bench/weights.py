@@ -6,7 +6,9 @@ program made. The layout (leaf names and shapes) is the program's
 parameter tree, read from ``jax.eval_shape`` of its initializer: that is
 the checkpoint format the engine loads. Values: norm scales 1, the
 embedding N(0, 1/d_model), every other matrix N(0, 1/fan_in), all in the
-dtype the program serves them in.
+dtype the program serves them in. With ``shardings`` (the engine's
+tensor-parallel placement) the same program writes each leaf straight into
+its shards; the values do not depend on the placement.
 """
 from __future__ import annotations
 
@@ -23,7 +25,7 @@ def seed_key(seed: int):
                               (seed >> 32) & 0x7FFFFFFF)
 
 
-def make_params(shape_tree, seed: int):
+def make_params(shape_tree, seed: int, shardings=None):
     flat, treedef = jax.tree_util.tree_flatten_with_path(shape_tree)
 
     def build(key):
@@ -40,4 +42,5 @@ def make_params(shape_tree, seed: int):
             out.append((draw * (1.0 / math.sqrt(fan_in))).astype(leaf.dtype))
         return jax.tree_util.tree_unflatten(treedef, out)
 
-    return jax.jit(build)(seed_key(seed))
+    kw = {} if shardings is None else {"out_shardings": shardings}
+    return jax.jit(build, **kw)(seed_key(seed))
